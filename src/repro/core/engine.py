@@ -56,6 +56,7 @@ from repro.core.telemetry import (
     nonfinite_count,
     record_spec as telemetry_record_spec,
     residual_mass,
+    scope,
     score_histogram,
     shared_divergence,
     update_norm,
@@ -231,142 +232,145 @@ def batched_sparse_round(
     validf = valid_blk.astype(emb.dtype)
     slot = jnp.arange(k_max)[None, :]
 
-    # -- upstream Top-K (Eq. 1-2): one fused kernel call across all clients
-    scores = change_scores(
-        emb.reshape(cl * ns_blk, d), hist.reshape(cl * ns_blk, d)
-    ).reshape(cl, ns_blk)
-    scores = jnp.where(valid_blk, scores, -jnp.inf)
-    up_idx = top_k_select(scores, k_max, entity_axis=ea)  # (cl, k_max) global
-    up_mask = (slot < k[:, None]) & jnp.take_along_axis(valid, up_idx, axis=1)
-    up_maskf = up_mask.astype(emb.dtype)
+    with scope("feds/comm/upload"):
+        # -- upstream Top-K (Eq. 1-2): one fused kernel call across all clients
+        scores = change_scores(
+            emb.reshape(cl * ns_blk, d), hist.reshape(cl * ns_blk, d)
+        ).reshape(cl, ns_blk)
+        scores = jnp.where(valid_blk, scores, -jnp.inf)
+        up_idx = top_k_select(scores, k_max, entity_axis=ea)  # (cl, k_max) global
+        up_mask = (slot < k[:, None]) & jnp.take_along_axis(valid, up_idx, axis=1)
+        up_maskf = up_mask.astype(emb.dtype)
 
-    # (cl, ns_blk) 0/1 — which of my local rows went upstream this round;
-    # under faults only participating clients compute an upload at all
-    sent_maskf = up_maskf if faults is None else up_maskf * faults.part[:, None]
-    uploaded = eshard.scatter_add_vec(
-        jnp.zeros((cl, ns_blk), emb.dtype), up_idx, sent_maskf, ea
-    )
-    new_hist = jnp.where(uploaded[:, :, None] > 0, emb, hist)
-
-    vals = eshard.dist_take_rows(emb, up_idx, ea)  # (cl, k_max, d)
-    if codec.has_residual:
-        # error feedback: re-inject the banked residual before encoding, bank
-        # the fresh encode error after.  Only rows a participating client
-        # actually encoded refresh the bank — a dropped-in-flight upload
-        # still banked its error (the sender cannot know), an absent client
-        # banked nothing.
-        res_sel = eshard.dist_take_rows(res, up_idx, ea)
-        corrected = vals + res_sel * up_maskf[:, :, None]
-        vals = codec.roundtrip(corrected.reshape(-1, d)).reshape(cl, k_max, d)
-        err_rows = (corrected - vals) * sent_maskf[:, :, None]
-        err_full = eshard.scatter_add_rows(
-            jnp.zeros((cl, ns_blk, d), emb.dtype), up_idx, err_rows, ea
+        # (cl, ns_blk) 0/1 — which of my local rows went upstream this round;
+        # under faults only participating clients compute an upload at all
+        sent_maskf = up_maskf if faults is None else up_maskf * faults.part[:, None]
+        uploaded = eshard.scatter_add_vec(
+            jnp.zeros((cl, ns_blk), emb.dtype), up_idx, sent_maskf, ea
         )
-        new_res = jnp.where(uploaded[:, :, None] > 0, err_full, res)
-    else:
-        vals = codec.roundtrip(vals.reshape(-1, d)).reshape(cl, k_max, d)
-        new_res = res
+        new_hist = jnp.where(uploaded[:, :, None] > 0, emb, hist)
 
-    # the message CONTRIBUTED to this round's Eq. 3 aggregate: normally the
-    # fresh wire-coded upload (delivery-masked under faults); stragglers
-    # contribute the head of their in-flight queue — the message they sent
-    # ``lag`` sparse rounds ago — while the fresh message is pushed at the
-    # tail (masked to zero for non-stragglers, whose queues stay empty)
-    if faults is None:
-        msg_maskf = up_maskf
-    else:
-        msg_maskf = up_maskf * (faults.part * faults.up_ok)[:, None]
-    if queue is not None:
-        q_idx, q_val, q_msk = queue
-        stragb = straggler[:, None] > 0.5
-        contrib_idx = jnp.where(stragb, q_idx[:, 0], up_idx)
-        contrib_val = jnp.where(stragb[:, :, None], q_val[:, 0], vals)
-        contrib_msk = jnp.where(stragb, q_msk[:, 0], msg_maskf)
-        new_queue = (
-            jnp.concatenate([q_idx[:, 1:], up_idx[:, None]], axis=1),
-            jnp.concatenate([q_val[:, 1:], vals[:, None]], axis=1),
-            jnp.concatenate(
-                [q_msk[:, 1:], (msg_maskf * straggler[:, None])[:, None]],
-                axis=1,
-            ),
+        vals = eshard.dist_take_rows(emb, up_idx, ea)  # (cl, k_max, d)
+        if codec.has_residual:
+            # error feedback: re-inject the banked residual before encoding, bank
+            # the fresh encode error after.  Only rows a participating client
+            # actually encoded refresh the bank — a dropped-in-flight upload
+            # still banked its error (the sender cannot know), an absent client
+            # banked nothing.
+            res_sel = eshard.dist_take_rows(res, up_idx, ea)
+            corrected = vals + res_sel * up_maskf[:, :, None]
+            vals = codec.roundtrip(corrected.reshape(-1, d)).reshape(cl, k_max, d)
+            err_rows = (corrected - vals) * sent_maskf[:, :, None]
+            err_full = eshard.scatter_add_rows(
+                jnp.zeros((cl, ns_blk, d), emb.dtype), up_idx, err_rows, ea
+            )
+            new_res = jnp.where(uploaded[:, :, None] > 0, err_full, res)
+        else:
+            vals = codec.roundtrip(vals.reshape(-1, d)).reshape(cl, k_max, d)
+            new_res = res
+
+    with scope("feds/comm/aggregate"):
+        # the message CONTRIBUTED to this round's Eq. 3 aggregate: normally the
+        # fresh wire-coded upload (delivery-masked under faults); stragglers
+        # contribute the head of their in-flight queue — the message they sent
+        # ``lag`` sparse rounds ago — while the fresh message is pushed at the
+        # tail (masked to zero for non-stragglers, whose queues stay empty)
+        if faults is None:
+            msg_maskf = up_maskf
+        else:
+            msg_maskf = up_maskf * (faults.part * faults.up_ok)[:, None]
+        if queue is not None:
+            q_idx, q_val, q_msk = queue
+            stragb = straggler[:, None] > 0.5
+            contrib_idx = jnp.where(stragb, q_idx[:, 0], up_idx)
+            contrib_val = jnp.where(stragb[:, :, None], q_val[:, 0], vals)
+            contrib_msk = jnp.where(stragb, q_msk[:, 0], msg_maskf)
+            new_queue = (
+                jnp.concatenate([q_idx[:, 1:], up_idx[:, None]], axis=1),
+                jnp.concatenate([q_val[:, 1:], vals[:, None]], axis=1),
+                jnp.concatenate(
+                    [q_msk[:, 1:], (msg_maskf * straggler[:, None])[:, None]],
+                    axis=1,
+                ),
+            )
+        else:
+            contrib_idx, contrib_val, contrib_msk = up_idx, vals, msg_maskf
+            new_queue = None
+
+        # this client's wire-coded contribution scattered back to row positions,
+        # for the Eq. 3 own-contribution subtraction below
+        own_wire = eshard.scatter_add_rows(
+            jnp.zeros((cl, ns_blk, d), emb.dtype), contrib_idx,
+            contrib_val * contrib_msk[:, :, None], ea,
         )
-    else:
-        contrib_idx, contrib_val, contrib_msk = up_idx, vals, msg_maskf
-        new_queue = None
+        if faults is None and queue is None:
+            uploaded_contrib = uploaded
+        else:
+            uploaded_contrib = eshard.scatter_add_vec(
+                jnp.zeros((cl, ns_blk), emb.dtype), contrib_idx, contrib_msk, ea
+            )
 
-    # this client's wire-coded contribution scattered back to row positions,
-    # for the Eq. 3 own-contribution subtraction below
-    own_wire = eshard.scatter_add_rows(
-        jnp.zeros((cl, ns_blk, d), emb.dtype), contrib_idx,
-        contrib_val * contrib_msk[:, :, None], ea,
-    )
-    if faults is None and queue is None:
-        uploaded_contrib = uploaded
-    else:
-        uploaded_contrib = eshard.scatter_add_vec(
-            jnp.zeros((cl, ns_blk), emb.dtype), contrib_idx, contrib_msk, ea
+        # -- exchange: one all-gather of fixed-size buffers (no-op on host)
+        if faults is None and queue is None:
+            up_gid = jnp.where(
+                up_mask, jnp.take_along_axis(gid, up_idx, axis=1), num_global
+            )
+        else:
+            up_gid = jnp.where(
+                contrib_msk > 0,
+                jnp.take_along_axis(gid, contrib_idx, axis=1), num_global,
+            )
+        ex_vals, ex_msk = contrib_val, contrib_msk
+        if axis_name is not None:
+            up_gid = jax.lax.all_gather(up_gid, axis_name).reshape(-1, k_max)
+            ex_vals = jax.lax.all_gather(ex_vals, axis_name).reshape(-1, k_max, d)
+            ex_msk = jax.lax.all_gather(ex_msk, axis_name).reshape(-1, k_max)
+
+        # -- Eq. 3 over the global entity space (+1 padding segment); under
+        # entity sharding this runs redundantly per shard on replicated inputs,
+        # preserving the unsharded f32 summation order bit for bit.  The
+        # existence weights are already existence x participation: absent or
+        # undelivered messages arrive with mask 0, so a zero-participant round
+        # produces an all-zero aggregate and priority — a no-op, not a NaN.
+        agg, cnt = segment_aggregate(
+            up_gid.reshape(-1),
+            (ex_vals * ex_msk[:, :, None]).reshape(-1, d),
+            ex_msk.reshape(-1),
+            num_global + 1,
         )
 
-    # -- exchange: one all-gather of fixed-size buffers (no-op on host)
-    if faults is None and queue is None:
-        up_gid = jnp.where(
-            up_mask, jnp.take_along_axis(gid, up_idx, axis=1), num_global
+        # -- personalized views: subtract the own wire-coded contribution
+        agg_rows = agg[gid_blk] - own_wire
+        pri_rows = (cnt[gid_blk] - uploaded_contrib) * validf
+    with scope("feds/comm/download"):
+        # downstream leg crosses the wire too
+        agg_rows = codec.roundtrip(agg_rows.reshape(-1, d)).reshape(cl, ns_blk, d)
+
+        # -- downstream Top-K by priority; jitter < 1 never reorders priorities
+        rank = jnp.where(valid_blk, pri_rows + jitter_blk, -1.0)
+        dn_idx = top_k_select(rank, k_max, entity_axis=ea)
+        dn_mask = (slot < k[:, None]) & (
+            eshard.dist_take_vec(pri_rows, dn_idx, ea) > 0
         )
-    else:
-        up_gid = jnp.where(
-            contrib_msk > 0,
-            jnp.take_along_axis(gid, contrib_idx, axis=1), num_global,
+        if faults is not None:
+            # the server only selects (and bills) rows for participating clients
+            dn_mask = dn_mask & (faults.part[:, None] > 0.5)
+        sign = eshard.scatter_add_vec(
+            jnp.zeros((cl, ns_blk), jnp.int8), dn_idx, dn_mask.astype(jnp.int8), ea
         )
-    ex_vals, ex_msk = contrib_val, contrib_msk
-    if axis_name is not None:
-        up_gid = jax.lax.all_gather(up_gid, axis_name).reshape(-1, k_max)
-        ex_vals = jax.lax.all_gather(ex_vals, axis_name).reshape(-1, k_max, d)
-        ex_msk = jax.lax.all_gather(ex_msk, axis_name).reshape(-1, k_max)
+        down_count = dn_mask.sum(axis=1).astype(jnp.int32)
+        if faults is not None:
+            # a lost download never lands; the bytes were still sent (and the
+            # down_count above — which drives the ledger — already charged them)
+            sign = sign * (faults.dn_ok[:, None] > 0.5).astype(jnp.int8)
 
-    # -- Eq. 3 over the global entity space (+1 padding segment); under
-    # entity sharding this runs redundantly per shard on replicated inputs,
-    # preserving the unsharded f32 summation order bit for bit.  The
-    # existence weights are already existence x participation: absent or
-    # undelivered messages arrive with mask 0, so a zero-participant round
-    # produces an all-zero aggregate and priority — a no-op, not a NaN.
-    agg, cnt = segment_aggregate(
-        up_gid.reshape(-1),
-        (ex_vals * ex_msk[:, :, None]).reshape(-1, d),
-        ex_msk.reshape(-1),
-        num_global + 1,
-    )
-
-    # -- personalized views: subtract the own wire-coded contribution
-    agg_rows = agg[gid_blk] - own_wire
-    pri_rows = (cnt[gid_blk] - uploaded_contrib) * validf
-    # downstream leg crosses the wire too
-    agg_rows = codec.roundtrip(agg_rows.reshape(-1, d)).reshape(cl, ns_blk, d)
-
-    # -- downstream Top-K by priority; jitter < 1 never reorders priorities
-    rank = jnp.where(valid_blk, pri_rows + jitter_blk, -1.0)
-    dn_idx = top_k_select(rank, k_max, entity_axis=ea)
-    dn_mask = (slot < k[:, None]) & (
-        eshard.dist_take_vec(pri_rows, dn_idx, ea) > 0
-    )
-    if faults is not None:
-        # the server only selects (and bills) rows for participating clients
-        dn_mask = dn_mask & (faults.part[:, None] > 0.5)
-    sign = eshard.scatter_add_vec(
-        jnp.zeros((cl, ns_blk), jnp.int8), dn_idx, dn_mask.astype(jnp.int8), ea
-    )
-    down_count = dn_mask.sum(axis=1).astype(jnp.int32)
-    if faults is not None:
-        # a lost download never lands; the bytes were still sent (and the
-        # down_count above — which drives the ledger — already charged them)
-        sign = sign * (faults.dn_ok[:, None] > 0.5).astype(jnp.int8)
-
-    # -- Eq. 4 masked row update, fused over the flattened client axis
-    new_emb = kernel_ops.sparse_apply(
-        emb.reshape(-1, d),
-        agg_rows.reshape(-1, d),
-        pri_rows.reshape(-1),
-        sign.reshape(-1),
-    ).reshape(cl, ns_blk, d).astype(emb.dtype)
+        # -- Eq. 4 masked row update, fused over the flattened client axis
+        new_emb = kernel_ops.sparse_apply(
+            emb.reshape(-1, d),
+            agg_rows.reshape(-1, d),
+            pri_rows.reshape(-1),
+            sign.reshape(-1),
+        ).reshape(cl, ns_blk, d).astype(emb.dtype)
     out = (new_emb, new_hist, down_count)
     if res is not None:
         out = out + (new_res,)
@@ -419,6 +423,7 @@ def batched_sparse_round(
     return out
 
 
+@scope("feds/comm/sync")
 def batched_sync_round(
     emb: jnp.ndarray,  # (C_local, Ns_max, D)
     gid: jnp.ndarray,

@@ -65,6 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import eshard, telemetry
+from repro.core.telemetry import scope
 from repro.data.partition import ClientData
 from repro.kernels import ops as kernel_ops
 from repro.kge import scoring as kge_scoring
@@ -267,6 +268,24 @@ class BatchedEvaluator:
                                num_words=self.e_pad // WORD_BITS)
             for s in splits
         }
+        # work counters of the ``eval`` telemetry span, per split: candidate
+        # scores an evaluate call computes (both legs, rows and candidates
+        # padded as the program runs them) and those of real triples
+        # against their client's real entities
+        if self._eaxis is None:
+            scan_w = self.e_pad
+        else:  # each shard scans its own chunk-padded block
+            scan_w = n_e * (-(-(self.e_pad // n_e) // self.chunk) * self.chunk)
+        n_ent = [int(d.num_entities) for d in datas]
+        self._eval_work = {
+            s: {
+                "scores": len(datas) * b.triples.shape[1] * 2 * scan_w,
+                "scores_real": sum(
+                    2 * int(n) * e for n, e in zip(np.asarray(b.count), n_ent)
+                ),
+            }
+            for s, b in self.banks.items()
+        }
         self.eval_core = self._make_eval_core()
         self._rank_core = self._make_rank_core()
         if mesh is None:
@@ -308,6 +327,7 @@ class BatchedEvaluator:
         chunk, e_pad = self.chunk, self.e_pad
         eaxis = self._eaxis
 
+        @scope("feds/eval")
         def rank_core(params, bank: EvalBank):
             """Filtered ranks ``(rank_t, rank_h)``, each (C, B_max) int32.
 
@@ -326,11 +346,12 @@ class BatchedEvaluator:
             else:
                 span = -(-e_blk // chunk) * chunk
                 base = eshard.shard_offset(eaxis, e_blk)
-            ent_p = jnp.pad(ent, ((0, 0), (0, span - e_blk), (0, 0)))
-            ftw, fhw = bank.ft_words, bank.fh_words
-            if span > e_blk and eaxis is not None:
-                pw = ((0, 0), (0, 0), (0, (span - e_blk) // WORD_BITS))
-                ftw, fhw = jnp.pad(ftw, pw), jnp.pad(fhw, pw)
+            with scope("feds/eval/filter"):
+                ent_p = jnp.pad(ent, ((0, 0), (0, span - e_blk), (0, 0)))
+                ftw, fhw = bank.ft_words, bank.fh_words
+                if span > e_blk and eaxis is not None:
+                    pw = ((0, 0), (0, 0), (0, (span - e_blk) // WORD_BITS))
+                    ftw, fhw = jnp.pad(ftw, pw), jnp.pad(fhw, pw)
             tri = bank.triples
             h, r, t = tri[..., 0], tri[..., 1], tri[..., 2]
             h_e = eshard.dist_take_rows(ent, h, eaxis)  # (C, B, D)
@@ -346,6 +367,7 @@ class BatchedEvaluator:
             n_words = chunk // WORD_BITS  # chunk is a whole-word multiple
             bit = jnp.arange(WORD_BITS, dtype=jnp.uint32)
 
+            @scope("feds/eval/filter")
             def unpack_chunk(words, w0):
                 """Slice the chunk's packed words ONCE and expand bits
                 in-register: (C, B, W) -> (C, B, chunk) 0/1."""
@@ -356,44 +378,48 @@ class BatchedEvaluator:
                 cnt_t, cnt_h = carry
                 cand_loc = e0 + jnp.arange(chunk, dtype=jnp.int32)  # (Ec,)
                 cand = base + cand_loc  # global candidate ids
-                ce = jax.lax.dynamic_slice_in_dim(ent_p, e0, chunk, axis=1)
-                # both legs' candidate scores, (C, B, Ec) tiles
-                ts, hs = kernel_ops.kge_cand_scores(
-                    h_e, r_e, t_e, ce, method, gamma
-                )
+                with scope("feds/eval/score"):
+                    ce = jax.lax.dynamic_slice_in_dim(ent_p, e0, chunk, axis=1)
+                    # both legs' candidate scores, (C, B, Ec) tiles
+                    ts, hs = kernel_ops.kge_cand_scores(
+                        h_e, r_e, t_e, ce, method, gamma
+                    )
                 w0 = e0 // WORD_BITS
                 fb_t = unpack_chunk(ftw, w0)
                 fb_h = unpack_chunk(fhw, w0)
-                ok = cand[None, :] < bank.num_ent[:, None]  # (C, Ec)
-                if eaxis is not None:
-                    # span-padding candidates would alias the NEXT shard's
-                    # global ids — mask past this shard's block edge
-                    ok = ok & (cand_loc[None, :] < e_blk)
-                beat_t = (
-                    (ts > gold[:, :, None])
-                    & (fb_t == 0)
-                    & ok[:, None, :]
-                    & (cand[None, None, :] != t[:, :, None])
-                )
-                beat_h = (
-                    (hs > gold[:, :, None])
-                    & (fb_h == 0)
-                    & ok[:, None, :]
-                    & (cand[None, None, :] != h[:, :, None])
-                )
-                return (
-                    cnt_t + beat_t.sum(-1).astype(jnp.int32),
-                    cnt_h + beat_h.sum(-1).astype(jnp.int32),
-                ), None
+                with scope("feds/eval/rank"):
+                    ok = cand[None, :] < bank.num_ent[:, None]  # (C, Ec)
+                    if eaxis is not None:
+                        # span-padding candidates would alias the NEXT
+                        # shard's global ids — mask past this shard's block
+                        # edge
+                        ok = ok & (cand_loc[None, :] < e_blk)
+                    beat_t = (
+                        (ts > gold[:, :, None])
+                        & (fb_t == 0)
+                        & ok[:, None, :]
+                        & (cand[None, None, :] != t[:, :, None])
+                    )
+                    beat_h = (
+                        (hs > gold[:, :, None])
+                        & (fb_h == 0)
+                        & ok[:, None, :]
+                        & (cand[None, None, :] != h[:, :, None])
+                    )
+                    return (
+                        cnt_t + beat_t.sum(-1).astype(jnp.int32),
+                        cnt_h + beat_h.sum(-1).astype(jnp.int32),
+                    ), None
 
             (cnt_t, cnt_h), _ = jax.lax.scan(
                 step, (zero, zero),
                 jnp.arange(0, span, chunk, dtype=jnp.int32),
             )
-            if eaxis is not None:
-                cnt_t = jax.lax.psum(cnt_t, eaxis)
-                cnt_h = jax.lax.psum(cnt_h, eaxis)
-            return cnt_t + 1, cnt_h + 1
+            with scope("feds/eval/rank"):
+                if eaxis is not None:
+                    cnt_t = jax.lax.psum(cnt_t, eaxis)
+                    cnt_h = jax.lax.psum(cnt_h, eaxis)
+                return cnt_t + 1, cnt_h + 1
 
         return rank_core
 
@@ -404,6 +430,10 @@ class BatchedEvaluator:
             """(C, 5) per-client ``[mrr, hits@1, hits@3, hits@10, count]``
             scalar block (column order fixed by :data:`HITS_LEVELS`)."""
             rank_t, rank_h = rank_core(params, bank)
+            return metric_block(rank_t, rank_h, bank)
+
+        @scope("feds/eval/rank")
+        def metric_block(rank_t, rank_h, bank: EvalBank):
             b_max = rank_t.shape[1]
             valid = jnp.arange(b_max)[None, :] < bank.count[:, None]
             rt = rank_t.astype(jnp.float32)
@@ -432,7 +462,7 @@ class BatchedEvaluator:
     def evaluate(self, params: dict, split: str) -> np.ndarray:
         """Run the compiled program; returns the (C, EVAL_BLOCK_COLS) block
         as numpy — the ONLY host transfer an eval boundary performs."""
-        with telemetry.span("eval", split=split):
+        with telemetry.span("eval", split=split, **self._eval_work[split]):
             return np.asarray(self._eval(params, self.banks[split]))
 
     def ranks(self, params: dict, split: str) -> tuple[np.ndarray, np.ndarray]:

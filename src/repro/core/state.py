@@ -73,7 +73,9 @@ from repro.core.telemetry import (
     nonfinite_count,
     record_spec,
     residual_mass,
+    scope,
     shared_divergence,
+    span,
     telemetry_spec,
     update_norm,
 )
@@ -253,6 +255,11 @@ class CycleEngine:
         # ``where``s — are dead weight, so they are compiled out entirely.
         self._uniform_steps = bool(step_mask.all())
         self._uniform_batches = bool((sample_w == 1.0).all())
+        # work counters of the ``round`` telemetry span, fixed here so a
+        # span never reads the device: client-steps the trainer runs per
+        # round (padding included), and those that train on a real batch
+        self.client_steps = self.num_clients * self.scan_len
+        self.client_steps_real = int(step_mask.sum())
         gather_idx = np.zeros((self.num_clients, self.ns_pad), np.int32)
         scatter_idx = np.full((self.num_clients, self.ns_pad), self.e_pad, np.int32)
         for c, v in enumerate(self.views):
@@ -316,6 +323,7 @@ class CycleEngine:
         # shards; consts.cids slices each shard's clients.
         sched = self._sched
 
+        @scope("feds/comm")
         def round_faults_of(consts, t):
             rf = draw_round_faults(sched, t, self.num_clients)
             return RoundFaults(
@@ -557,11 +565,6 @@ class CycleEngine:
             def step_fn(carry, x):
                 params_f, opt_f = carry
                 p, nt, nh = x  # (C, B, 3), (C, B, N)
-                r = (p[:, :, 1] + roff[:, None]).reshape(-1)
-                e_idx = jnp.concatenate([
-                    p[:, :, 0].reshape(-1), p[:, :, 2].reshape(-1),
-                    nt.reshape(-1), nh.reshape(-1),
-                ])
 
                 def loss_fn(rows, rel):
                     pos_s, neg_s = scores_of(rows, rel, cb)
@@ -569,14 +572,26 @@ class CycleEngine:
                     loss_c = (per.reshape(c_n, b_max) * wn).sum(axis=1) / 2.0
                     return loss_c.sum(), loss_c
 
-                (_, loss_c), (g_rows, g_rel) = jax.value_and_grad(
-                    loss_fn, argnums=(0, 1), has_aux=True
-                )(gather_rows(params_f["entity"], e_idx), params_f["relation"][r])
-                grads = {
-                    "entity": scatter_grads(params_f["entity"], e_idx, g_rows),
-                    "relation": jnp.zeros_like(params_f["relation"]).at[r].add(g_rel),
-                }
-                params_f, opt_f = adam_update(grads, opt_f, params_f, lr)
+                with scope("feds/train/gather"):
+                    r = (p[:, :, 1] + roff[:, None]).reshape(-1)
+                    e_idx = jnp.concatenate([
+                        p[:, :, 0].reshape(-1), p[:, :, 2].reshape(-1),
+                        nt.reshape(-1), nh.reshape(-1),
+                    ])
+                    rows = gather_rows(params_f["entity"], e_idx)
+                    rel = params_f["relation"][r]
+                with scope("feds/train/score_grad"):
+                    (_, loss_c), (g_rows, g_rel) = jax.value_and_grad(
+                        loss_fn, argnums=(0, 1), has_aux=True
+                    )(rows, rel)
+                with scope("feds/train/scatter"):
+                    rel_tab = params_f["relation"]
+                    grads = {
+                        "entity": scatter_grads(params_f["entity"], e_idx, g_rows),
+                        "relation": jnp.zeros_like(rel_tab).at[r].add(g_rel),
+                    }
+                with scope("feds/train/adam"):
+                    params_f, opt_f = adam_update(grads, opt_f, params_f, lr)
                 return (params_f, opt_f), loss_c
 
             (params_f, opt_f), losses = jax.lax.scan(
@@ -602,33 +617,37 @@ class CycleEngine:
         # ---- heterogeneous fallback: vmap over clients with masked steps
         def batch_grads(params, p, nt, nh, weight):
             ent, rel_tab = params["entity"], params["relation"]
-            h, r, t = p[:, 0], p[:, 1], p[:, 2]
-            idx = jnp.concatenate([h, t, nt.reshape(-1), nh.reshape(-1)])
 
             def loss_fn(rows, rel):
                 pos_s, neg_s = scores_of(rows, rel, b_max)
                 return loss_from_scores(pos_s, neg_s, method, temp, weight)
 
-            if eaxis is None:
-                rows_in = ent[idx]
-            else:  # collectives batch under the client vmap (one per shard)
-                rows_in = eshard._take_rows_one(ent, idx, eaxis)
-            loss, (g_rows, g_rel) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
-                rows_in, rel_tab[r]
-            )
-            if eaxis is None:
-                g_ent = jnp.zeros_like(ent).at[idx].add(g_rows)
-            else:
-                e_blk = ent.shape[0]
-                loc = idx - jax.lax.axis_index(eaxis) * e_blk
-                own = (loc >= 0) & (loc < e_blk)
-                g_ent = jnp.zeros_like(ent).at[
-                    jnp.where(own, loc, e_blk)
-                ].add(g_rows, mode="drop")
-            grads = {
-                "entity": g_ent,
-                "relation": jnp.zeros_like(rel_tab).at[r].add(g_rel),
-            }
+            with scope("feds/train/gather"):
+                h, r, t = p[:, 0], p[:, 1], p[:, 2]
+                idx = jnp.concatenate([h, t, nt.reshape(-1), nh.reshape(-1)])
+                if eaxis is None:
+                    rows_in = ent[idx]
+                else:  # collectives batch under the client vmap (one per shard)
+                    rows_in = eshard._take_rows_one(ent, idx, eaxis)
+                rel_in = rel_tab[r]
+            with scope("feds/train/score_grad"):
+                loss, (g_rows, g_rel) = jax.value_and_grad(
+                    loss_fn, argnums=(0, 1)
+                )(rows_in, rel_in)
+            with scope("feds/train/scatter"):
+                if eaxis is None:
+                    g_ent = jnp.zeros_like(ent).at[idx].add(g_rows)
+                else:
+                    e_blk = ent.shape[0]
+                    loc = idx - jax.lax.axis_index(eaxis) * e_blk
+                    own = (loc >= 0) & (loc < e_blk)
+                    g_ent = jnp.zeros_like(ent).at[
+                        jnp.where(own, loc, e_blk)
+                    ].add(g_rows, mode="drop")
+                grads = {
+                    "entity": g_ent,
+                    "relation": jnp.zeros_like(rel_tab).at[r].add(g_rel),
+                }
             return loss, grads
 
         def train_one(params, opt, pos, neg_t, neg_h, s_w, s_mask):
@@ -638,7 +657,8 @@ class CycleEngine:
                 params, opt = carry
                 p, nt, nh, ok = x
                 loss, grads = batch_grads(params, p, nt, nh, weight)
-                params, opt = masked_adam_update(grads, opt, params, lr, ok)
+                with scope("feds/train/adam"):
+                    params, opt = masked_adam_update(grads, opt, params, lr, ok)
                 return (params, opt), jnp.where(ok, loss, 0.0)
 
             (params, opt), losses = jax.lax.scan(
@@ -657,10 +677,13 @@ class CycleEngine:
                 ),
             )
 
+        @scope("feds/train")
         def train_core(arrays, kb, kj, consts):
-            pos, neg_t, neg_h = jax.vmap(sample_one, in_axes=(0, 0, 0, 0, None))(
-                consts.cids, consts.triples, consts.num_train, consts.num_ent, kb
-            )
+            with scope("feds/train/sample"):
+                pos, neg_t, neg_h = jax.vmap(
+                    sample_one, in_axes=(0, 0, 0, 0, None)
+                )(consts.cids, consts.triples, consts.num_train,
+                  consts.num_ent, kb)
             params, opt = arrays.params, arrays.opt
             if eaxis is not None:
                 # every entity shard applies the same relation updates from
@@ -690,11 +713,14 @@ class CycleEngine:
             # here so the per-round oracle consumes bit-identical noise.
             # Always drawn at the LOGICAL ns_max shape — the draw shape feeds
             # the PRNG, so padding must happen after, not in the draw.
-            jitter = jax.vmap(
-                lambda cid: jax.random.uniform(jax.random.fold_in(kj, cid), (ns_max,))
-            )(consts.cids)
-            if ns_pad > ns_max:
-                jitter = jnp.pad(jitter, ((0, 0), (0, ns_pad - ns_max)))
+            with scope("feds/train/sample"):
+                jitter = jax.vmap(
+                    lambda cid: jax.random.uniform(
+                        jax.random.fold_in(kj, cid), (ns_max,)
+                    )
+                )(consts.cids)
+                if ns_pad > ns_max:
+                    jitter = jnp.pad(jitter, ((0, 0), (0, ns_pad - ns_max)))
             return (
                 StateArrays(
                     params, opt, arrays.hist, arrays.res, arrays.faults,
@@ -713,6 +739,7 @@ class CycleEngine:
         has_stragglers = self._sched is not None and self._sched.has_stragglers
         tel = self._tel
 
+        @scope("feds/comm")
         def comm_core(arrays, jitter, consts, do_sync, rf=None):
             fa = arrays.faults
             new_tel = arrays.tel
@@ -950,6 +977,16 @@ class CycleEngine:
         key, kb, kj = jax.random.split(key, 3)
         return key, kb, kj
 
+    def _round_span(self, kind: str, t=None, rounds: int = 1):
+        """The ``round`` telemetry span of a dispatch that trains
+        ``rounds`` rounds (0 for a communication round alone)."""
+        attrs = {} if t is None else {"t": t}
+        return span(
+            "round", kind=kind, **attrs,
+            client_steps=rounds * self.client_steps,
+            client_steps_real=rounds * self.client_steps_real,
+        )
+
     def train_cycle(self, state: FederationState):
         """``local_epochs`` of device training.  Returns (state', jitter, loss).
 
@@ -958,8 +995,9 @@ class CycleEngine:
         the jitter output feeds the sparse round so the two-program path
         consumes the same random stream as the fused program.
         """
-        key, kb, kj = self._advance(state.key)
-        arrays, jitter, loss = self._train(state.arrays, kb, kj, self.consts)
+        with self._round_span("train"):
+            key, kb, kj = self._advance(state.key)
+            arrays, jitter, loss = self._train(state.arrays, kb, kj, self.consts)
         return FederationState(arrays, key), jitter, loss
 
     def _require_t(self, t):
@@ -979,18 +1017,19 @@ class CycleEngine:
         required — the round's participation/drop masks are drawn from it
         inside the program.
         """
-        if self._sched is not None:
-            tt = self._require_t(t)
-            if sync:
-                out = self._comm_sync_f(state.arrays, self.consts, tt)
+        with self._round_span("sync" if sync else "sparse", t, rounds=0):
+            if self._sched is not None:
+                tt = self._require_t(t)
+                if sync:
+                    out = self._comm_sync_f(state.arrays, self.consts, tt)
+                else:
+                    out = self._comm_sparse_f(
+                        state.arrays, jitter, self.consts, tt
+                    )
+            elif sync:
+                out = self._comm_sync(state.arrays, self.consts)
             else:
-                out = self._comm_sparse_f(
-                    state.arrays, jitter, self.consts, tt
-                )
-        elif sync:
-            out = self._comm_sync(state.arrays, self.consts)
-        else:
-            out = self._comm_sparse(state.arrays, jitter, self.consts)
+                out = self._comm_sparse(state.arrays, jitter, self.consts)
         if self._tel:
             arrays, down, rec = out
             return FederationState(arrays, state.key), down, rec
@@ -1006,13 +1045,14 @@ class CycleEngine:
         :class:`~repro.core.telemetry.RoundTelemetry` when the engine was
         built with ``telemetry=True``.  ``t`` as in :meth:`comm_round`.
         """
-        key, kb, kj = self._advance(state.key)
-        if self._sched is not None:
-            fn = self._fused_sync_f if sync else self._fused_sparse_f
-            out = fn(state.arrays, kb, kj, self.consts, self._require_t(t))
-        else:
-            fn = self._fused_sync if sync else self._fused_sparse
-            out = fn(state.arrays, kb, kj, self.consts)
+        with self._round_span("sync" if sync else "sparse", t):
+            key, kb, kj = self._advance(state.key)
+            if self._sched is not None:
+                fn = self._fused_sync_f if sync else self._fused_sparse_f
+                out = fn(state.arrays, kb, kj, self.consts, self._require_t(t))
+            else:
+                fn = self._fused_sync if sync else self._fused_sparse
+                out = fn(state.arrays, kb, kj, self.consts)
         if self._tel:
             arrays, down, loss, rec = out
             return FederationState(arrays, key), down, loss, rec
@@ -1222,11 +1262,13 @@ class SuperstepEngine(CycleEngine):
         args = (state.arrays, state.key, self.consts)
         if self._sched is not None:
             args = args + (self._require_t(t0),)
+        with self._round_span("superstep", t0, rounds=len(kinds)):
+            out = fn(*args)
         if self._tel:
-            arrays, key, downs, losses, recs = fn(*args)
+            arrays, key, downs, losses, recs = out
             per_round = self._align(kinds, downs, recs)
         else:
-            arrays, key, downs, losses = fn(*args)
+            arrays, key, downs, losses = out
             per_round = self._align(kinds, downs)
         return FederationState(arrays, key), per_round, losses
 
@@ -1262,15 +1304,13 @@ class SuperstepEngine(CycleEngine):
         args = (state.arrays, state.key, self.consts)
         if self._sched is not None:
             args = args + (self._require_t(t0),)
+        with self._round_span("superstep", t0, rounds=len(kinds)):
+            out = fn(*args, evaluator.banks[split])
         if self._tel:
-            arrays, key, downs, losses, recs, blocks = fn(
-                *args, evaluator.banks[split]
-            )
+            arrays, key, downs, losses, recs, blocks = out
             per_round = self._align(kinds, downs, recs)
         else:
-            arrays, key, downs, losses, blocks = fn(
-                *args, evaluator.banks[split]
-            )
+            arrays, key, downs, losses, blocks = out
             per_round = self._align(kinds, downs)
         return (
             FederationState(arrays, key),

@@ -17,11 +17,18 @@ Two halves, matching the two places observability costs something:
 * **Host spans + sink** — :class:`TelemetrySink` writes newline-delimited
   JSON events (``run`` / ``round`` / ``eval`` / ``span`` / ``ledger``) to
   the path given by ``FederatedConfig.telemetry`` / ``--telemetry``;
-  :func:`span` times host-side stages (tiered staging, checkpoint writes,
-  eval readback) and is a shared no-op context manager when no sink is
-  installed, so call sites are unconditional.  Set
-  ``REPRO_TELEMETRY_PROFILE=1`` to additionally wrap spans in
-  ``jax.profiler.TraceAnnotation``.
+  :func:`span` marks host-side stages (rounds, ledger flushes, eval
+  calls, tiered staging, checkpoint writes).  Every span opens a
+  ``jax.profiler.TraceAnnotation`` named ``telemetry/<name>`` that carries
+  the span's attributes (its work counters among them), so a profiler
+  trace shows the program's own host spans on the device's clock; the
+  annotation costs next to nothing while no profiler runs.  A ``span``
+  JSONL event is written only when a sink is installed.
+* **Named scopes** — :data:`SCOPES` names the layers of the compiled
+  programs (the training step, the FedS round, the eval scan); the cores
+  in ``core/state.py``, ``core/engine.py`` and ``core/evaluation.py`` wrap
+  their work in :func:`scope`, so each device op of a trace carries its
+  layer in its HLO ``op_name``.
 
 ``tools/trace_report.py`` renders the JSONL into a per-round table and a
 bytes/MRR/participation summary, and checks the **reconciliation
@@ -33,7 +40,6 @@ from __future__ import annotations
 
 import contextlib
 import json
-import os
 import time
 from typing import NamedTuple, Optional
 
@@ -103,6 +109,20 @@ ROUND_EVENT_FIELDS = (
     "div_mean", "div_max", "upd_norm", "nonfinite",
     "up_bytes", "dn_bytes", "cache_hits", "cache_misses",
     "cache_evictions", "cum_params", "cum_bytes",
+)
+
+# The named scopes of the compiled programs, outermost first within each
+# layer.  A literal tuple, like ROUND_EVENT_FIELDS, so tools/docs_lint.py
+# can parse it without importing jax.  Leaf scopes nest inside their
+# parent (``feds/train``, ``feds/comm``, ``feds/eval``), which covers the
+# work between them; names are full paths, so a leaf's name stays whole
+# in an op's ``op_name`` however JAX wraps it (``transpose(jvp(...))``).
+SCOPES = (
+    "feds/train", "feds/train/sample", "feds/train/gather",
+    "feds/train/score_grad", "feds/train/scatter", "feds/train/adam",
+    "feds/comm", "feds/comm/upload", "feds/comm/aggregate",
+    "feds/comm/download", "feds/comm/sync",
+    "feds/eval", "feds/eval/filter", "feds/eval/score", "feds/eval/rank",
 )
 
 
@@ -258,17 +278,12 @@ class TelemetrySink:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        ann = None
-        if os.environ.get("REPRO_TELEMETRY_PROFILE"):
-            ann = jax.profiler.TraceAnnotation(f"telemetry/{name}")
-            ann.__enter__()
         t0 = time.perf_counter()
         try:
-            yield
+            with _annotation(name, attrs):
+                yield
         finally:
             dur = time.perf_counter() - t0
-            if ann is not None:
-                ann.__exit__(None, None, None)
             self.emit({"ev": "span", "name": name, "dur_s": dur, **attrs})
 
     def close(self) -> None:
@@ -278,7 +293,10 @@ class TelemetrySink:
 
 
 _ACTIVE: Optional[TelemetrySink] = None
-_NULL_SPAN = contextlib.nullcontext()
+
+
+def _annotation(name: str, attrs: dict):
+    return jax.profiler.TraceAnnotation(f"telemetry/{name}", **attrs)
 
 
 def active() -> Optional[TelemetrySink]:
@@ -304,11 +322,21 @@ def session(sink: Optional[TelemetrySink]):
 
 
 def span(name: str, **attrs):
-    """Time a host-side stage into the active sink.
+    """Mark a host-side stage: a ``telemetry/<name>`` profiler annotation
+    carrying ``attrs``, plus a timed ``span`` event in the active sink.
 
-    Call sites are unconditional: with no sink installed this returns one
-    shared ``nullcontext`` — no allocation, no timing, no event.
+    Call sites are unconditional.  With no sink installed this is the
+    annotation alone: no timing, no event.  ``attrs`` are host values
+    (Python ints and strings); a span never reads a device array, which
+    would add a host sync to the path it marks.
     """
     if _ACTIVE is None:
-        return _NULL_SPAN
+        return _annotation(name, attrs)
     return _ACTIVE.span(name, **attrs)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of :data:`SCOPES`."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; known: {SCOPES}")
+    return jax.named_scope(name)

@@ -235,46 +235,47 @@ def _flush_ledger(
     ``cache_stats`` (tiered engine) is a per-pending-round list of cache
     hit/miss/eviction deltas folded into the events.
     """
-    sparse_counts = [d for kind, d, _, _ in pending if kind == "sparse"]
-    dc_all = np.asarray(jnp.stack(sparse_counts)) if sparse_counts else None
-    recs = [r for _, _, _, r in pending if r is not None]
-    stacked = (
-        jax.tree.map(lambda *xs: np.asarray(jnp.stack(xs)), *recs)
-        if recs else None
-    )
-    i = 0
-    j = 0
-    for n, (kind, _, t, rec) in enumerate(pending):
-        part = (
-            host_round_faults(sched, t, len(views))[0]
-            if sched is not None else None
+    with telemetry.span("ledger_flush", rounds=len(pending)):
+        sparse_counts = [d for kind, d, _, _ in pending if kind == "sparse"]
+        dc_all = np.asarray(jnp.stack(sparse_counts)) if sparse_counts else None
+        recs = [r for _, _, _, r in pending if r is not None]
+        stacked = (
+            jax.tree.map(lambda *xs: np.asarray(jnp.stack(xs)), *recs)
+            if recs else None
         )
-        if kind == "sync":
-            for v in views:  # upload leg + download leg
-                if part is not None and not part[v.client_id]:
-                    continue
-                ledger.log_full_exchange(v.num_shared, dim)
-                ledger.log_full_exchange(v.num_shared, dim)
-        elif kind == "sparse":
-            for v, k_c, dc in zip(views, k_per_client, dc_all[i]):
-                if part is not None and not part[v.client_id]:
-                    continue
-                codec.log_upload(ledger, int(k_c), dim, v.num_shared)
-                codec.log_download(ledger, int(dc), dim, v.num_shared)
-            i += 1
-        ledger.end_round()
-        if sink is not None:
-            r = None
-            if rec is not None:
-                r = jax.tree.map(lambda a, j=j: a[j], stacked)
-                j += 1
-            _emit_round_event(
-                sink, codec, dim, views, kind, t, r,
-                cache=cache_stats[n] if cache_stats else None,
+        i = 0
+        j = 0
+        for n, (kind, _, t, rec) in enumerate(pending):
+            part = (
+                host_round_faults(sched, t, len(views))[0]
+                if sched is not None else None
             )
-    pending.clear()
-    if cache_stats is not None:
-        cache_stats.clear()
+            if kind == "sync":
+                for v in views:  # upload leg + download leg
+                    if part is not None and not part[v.client_id]:
+                        continue
+                    ledger.log_full_exchange(v.num_shared, dim)
+                    ledger.log_full_exchange(v.num_shared, dim)
+            elif kind == "sparse":
+                for v, k_c, dc in zip(views, k_per_client, dc_all[i]):
+                    if part is not None and not part[v.client_id]:
+                        continue
+                    codec.log_upload(ledger, int(k_c), dim, v.num_shared)
+                    codec.log_download(ledger, int(dc), dim, v.num_shared)
+                i += 1
+            ledger.end_round()
+            if sink is not None:
+                r = None
+                if rec is not None:
+                    r = jax.tree.map(lambda a, j=j: a[j], stacked)
+                    j += 1
+                _emit_round_event(
+                    sink, codec, dim, views, kind, t, r,
+                    cache=cache_stats[n] if cache_stats else None,
+                )
+        pending.clear()
+        if cache_stats is not None:
+            cache_stats.clear()
 
 
 def _emit_round_event(sink, codec, dim, views, kind, t, rec, cache=None):

@@ -35,3 +35,23 @@ def test_architecture_documents_contract_and_layout():
     for needle in ("mermaid", "(C, E_max, D)", "superstep", "WireCodec",
                    "bitwise"):
         assert needle in text, f"docs/architecture.md must mention {needle!r}"
+
+
+def test_docs_lint_names_an_undocumented_scope(tmp_path, monkeypatch):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "docs_lint", ROOT / "tools" / "docs_lint.py")
+    lint = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lint)
+    src = tmp_path / "src" / "repro" / "core"
+    src.mkdir(parents=True)
+    (src / "telemetry.py").write_text(
+        'SCOPES = (\n    "feds/train", "feds/train/gather",\n)\n')
+    (tmp_path / "PERF.md").write_text("| `feds/train` | ... |\n")
+    monkeypatch.setattr(lint, "ROOT", tmp_path)
+    assert lint.undocumented_scopes() == [
+        "PERF.md: named scope `feds/train/gather` is in the program but "
+        "not documented"]
+    (tmp_path / "PERF.md").write_text("`feds/train` and `feds/train/gather`")
+    assert lint.undocumented_scopes() == []

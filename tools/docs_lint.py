@@ -13,7 +13,9 @@ trajectory stays documented as producers are added.
 And telemetry schema sync: every field named in the ``ROUND_EVENT_FIELDS``
 literal of ``src/repro/core/telemetry.py`` must appear backticked in the
 "Telemetry dataflow" section of docs/architecture.md — the recorder can't
-grow an undocumented signal.
+grow an undocumented signal.  Likewise every named scope of the ``SCOPES``
+literal must appear backticked in PERF.md, whose layer table says which
+metric reads it.
 
   python tools/docs_lint.py
 
@@ -106,6 +108,26 @@ def telemetry_schema_drift() -> list[str]:
     return drift
 
 
+SCOPES_LITERAL = re.compile(r"^SCOPES\s*=\s*(\([^)]*\))", re.S | re.M)
+
+
+def undocumented_scopes() -> list[str]:
+    """Every named scope of the SCOPES literal in core/telemetry.py must
+    appear backticked in PERF.md (parsed textually, like the fields)."""
+    src = ROOT / "src" / "repro" / "core" / "telemetry.py"
+    perf = ROOT / "PERF.md"
+    if not src.exists() or not perf.exists():
+        return []
+    m = SCOPES_LITERAL.search(src.read_text())
+    if not m:
+        return ["src/repro/core/telemetry.py: SCOPES literal not found "
+                "(the docs sync check parses it textually)"]
+    documented = set(re.findall(r"`([^`\s]+)`", perf.read_text()))
+    return [f"PERF.md: named scope `{s}` is in the program but not "
+            f"documented" for s in ast.literal_eval(m.group(1))
+            if s not in documented]
+
+
 def main() -> int:
     bad = broken_links()
     for b in bad:
@@ -116,10 +138,14 @@ def main() -> int:
     drift = telemetry_schema_drift()
     for d in drift:
         print(f"TELEMETRY SCHEMA DRIFT  {d}")
+    scopes = undocumented_scopes()
+    for u in scopes:
+        print(f"UNDOCUMENTED SCOPE  {u}")
     files = len(doc_files())
-    if bad or undoc or drift:
+    if bad or undoc or drift or scopes:
         print(f"{len(bad)} broken link(s), {len(undoc)} undocumented "
-              f"benchmark record(s), {len(drift)} schema drift(s) "
+              f"benchmark record(s), {len(drift)} schema drift(s), "
+              f"{len(scopes)} undocumented scope(s) "
               f"across {files} file(s)")
         return 1
     print(f"docs lint OK ({files} files)")
