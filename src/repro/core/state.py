@@ -28,10 +28,13 @@ jit.  This module removes both:
 
 Client heterogeneity is expressed with static shapes throughout: triples are
 padded to ``T_max`` (samplers draw indices below the true count), batches to
-``B_max`` with zero-weight rows in the loss, scan steps to
-``local_epochs * S_max`` with pass-through optimizer steps
-(:func:`repro.train.optimizer.masked_adam_update`), and shared-entity rows to
-``Ns_max`` exactly as in the round engine.
+``B_max`` with zero-weight rows in the loss, and shared-entity rows to
+``Ns_max`` exactly as in the round engine.  Unequal batches-per-epoch are
+trained as static segments (:func:`step_segments`): each segment scans only
+the clients that still have real steps.  Under a mesh, where every shard runs
+one program over its own clients, the scan instead pads to
+``local_epochs * S_max`` steps with pass-through optimizer steps
+(:func:`repro.train.optimizer.masked_adam_update`).
 
 The per-round oracle path (``engine="batched"`` in the simulation) runs the
 SAME ``train_core`` / ``comm_core`` functions as two separate jits per round,
@@ -129,6 +132,31 @@ class CycleConsts(NamedTuple):
     valid: jnp.ndarray  # (C, Ns_max) shared-slot validity
     k: jnp.ndarray  # (C,) per-client upstream/downstream K
     straggler: jnp.ndarray  # (C,) f32 static straggler-set indicator
+
+
+def step_segments(steps, local_epochs: int) -> list[tuple[np.ndarray, np.ndarray]]:
+    """Static schedule of the real client-steps of one round.
+
+    Client ``c`` trains ``n_c = local_epochs * steps[c]`` real steps; its
+    ``j``-th is index ``e * S_max + i`` of the padded ``local_epochs * S_max``
+    scan, where ``j = e * steps[c] + i``.  With ``0 = b_0 < b_1 < ... < b_m``
+    the distinct ``n_c``, segment ``k`` covers positions ``[b_{k-1}, b_k)``
+    of every client with ``n_c >= b_k``.  Returns one ``(clients, idx)`` per
+    segment: the active client ids, ascending, and their
+    ``(len(clients), b_k - b_{k-1})`` scan indices.
+    """
+    steps = np.asarray(steps, np.int64)
+    s_max = int(steps.max())
+    n = local_epochs * steps
+    out, lo = [], 0
+    for hi in np.unique(n[n > 0]):
+        clients = np.flatnonzero(n >= hi)
+        j = np.arange(lo, hi)[None, :]
+        per = steps[clients][:, None]
+        out.append((clients.astype(np.int32),
+                    ((j // per) * s_max + j % per).astype(np.int32)))
+        lo = int(hi)
+    return out
 
 
 @dataclasses.dataclass
@@ -255,11 +283,26 @@ class CycleEngine:
         # ``where``s — are dead weight, so they are compiled out entirely.
         self._uniform_steps = bool(step_mask.all())
         self._uniform_batches = bool((sample_w == 1.0).all())
+        # Unequal batches-per-epoch in one program over every client: the
+        # trainer scans each segment of the static step schedule over the
+        # clients that still have real steps, and computes no padding.
+        # Under a mesh each shard runs one program over its own clients, so
+        # no per-client schedule fits every shard: the masked scan stays.
+        self._segments = (
+            step_segments(steps, self.local_epochs)
+            if not self._uniform_steps and mesh is None else None
+        )
         # work counters of the ``round`` telemetry span, fixed here so a
-        # span never reads the device: client-steps the trainer runs per
-        # round (padding included), and those that train on a real batch
-        self.client_steps = self.num_clients * self.scan_len
+        # span never reads the device: client-steps the trainer computes per
+        # round (padding included where the masked scan runs), those that
+        # train on a real batch, and the training scans a round runs
         self.client_steps_real = int(step_mask.sum())
+        if self._segments is None:
+            self.client_steps = self.num_clients * self.scan_len
+            self.train_segments = 1
+        else:
+            self.client_steps = sum(idx.size for _, idx in self._segments)
+            self.train_segments = len(self._segments)
         gather_idx = np.zeros((self.num_clients, self.ns_pad), np.int32)
         scatter_idx = np.full((self.num_clients, self.ns_pad), self.e_pad, np.int32)
         for c, v in enumerate(self.views):
@@ -467,6 +510,7 @@ class CycleEngine:
         uniform_steps = self._uniform_steps
         uniform_batches = self._uniform_batches
         eaxis, n_eshards = self._eaxis, self.n_eshards
+        segments, n_clients = self._segments, self.num_clients
 
         def sample_one(cid, tri, t_c, e_c, kb):
             """Pre-sample the whole cycle's batches for one client on device."""
@@ -614,7 +658,7 @@ class CycleEngine:
             )
             return params, new_opt, losses.mean(axis=0)
 
-        # ---- heterogeneous fallback: vmap over clients with masked steps
+        # ---- unequal batches-per-epoch: vmap over clients of a per-client scan
         def batch_grads(params, p, nt, nh, weight):
             ent, rel_tab = params["entity"], params["relation"]
 
@@ -650,7 +694,10 @@ class CycleEngine:
                 }
             return loss, grads
 
-        def train_one(params, opt, pos, neg_t, neg_h, s_w, s_mask):
+        def train_one(params, opt, pos, neg_t, neg_h, s_w, s_mask=None):
+            """One client's scan; ``s_mask`` flags its real steps, and a
+            padded one passes params and Adam state through (None: every
+            step is real).  Returns the per-step losses, 0 on padding."""
             weight = None if uniform_batches else s_w
 
             def step(carry, x):
@@ -658,14 +705,55 @@ class CycleEngine:
                 p, nt, nh, ok = x
                 loss, grads = batch_grads(params, p, nt, nh, weight)
                 with scope("feds/train/adam"):
-                    params, opt = masked_adam_update(grads, opt, params, lr, ok)
-                return (params, opt), jnp.where(ok, loss, 0.0)
+                    if ok is None:
+                        params, opt = adam_update(grads, opt, params, lr)
+                    else:
+                        params, opt = masked_adam_update(
+                            grads, opt, params, lr, ok
+                        )
+                if ok is not None:
+                    loss = jnp.where(ok, loss, 0.0)
+                return (params, opt), loss
 
             (params, opt), losses = jax.lax.scan(
                 step, (params, opt), (pos, neg_t, neg_h, s_mask)
             )
-            mean_loss = losses.sum() / jnp.maximum(s_mask.sum(), 1)
-            return params, opt, mean_loss
+            return params, opt, losses
+
+        def contiguous(ids):
+            """Ascending ``ids`` as a slice where they run without a gap."""
+            lo, hi = int(ids[0]), int(ids[-1]) + 1
+            return slice(lo, hi) if hi - lo == len(ids) else ids
+
+        def rows(tree, sel):
+            """``tree``'s leaves at client rows ``sel`` (None: all)."""
+            return tree if sel is None else jax.tree.map(lambda a: a[sel], tree)
+
+        def train_segmented(params, opt, pos, neg_t, neg_h, s_w):
+            """Scan each segment of the static step schedule over its active
+            clients, taken from and written back to the ``(C, ...)`` state.
+            Every computed step is real, so Adam runs unmasked.  Returns the
+            ``(C, L)`` per-step losses at their scan positions, 0 on
+            padding, as the masked scan gives them."""
+            losses = jnp.zeros((n_clients, scan_len), jnp.float32)
+            for clients, idx in segments:
+                sel = None if len(clients) == n_clients else contiguous(clients)
+                if (idx == idx[:1]).all():  # one static step range for all
+                    t = contiguous(idx[0])
+                    xs = [rows(a, sel)[:, t] for a in (pos, neg_t, neg_h)]
+                else:
+                    xs = [a[clients[:, None], idx] for a in (pos, neg_t, neg_h)]
+                p, o, seg_losses = jax.vmap(train_one)(
+                    *rows((params, opt), sel), *xs, rows(s_w, sel)
+                )
+                if sel is None:
+                    params, opt = p, o
+                else:
+                    params, opt = jax.tree.map(
+                        lambda a, b: a.at[sel].set(b), (params, opt), (p, o)
+                    )
+                losses = losses.at[clients[:, None], idx].set(seg_losses)
+            return params, opt, losses
 
         def map_relation(f, params, opt):
             """Apply ``f`` to the relation table and its Adam moments."""
@@ -700,9 +788,17 @@ class CycleEngine:
                     params, opt, pos, neg_t, neg_h, consts.sample_w
                 )
             else:
-                params, opt, loss = jax.vmap(train_one)(
-                    params, opt, pos, neg_t, neg_h,
-                    consts.sample_w, consts.step_mask,
+                if segments is None:
+                    params, opt, losses = jax.vmap(train_one)(
+                        params, opt, pos, neg_t, neg_h,
+                        consts.sample_w, consts.step_mask,
+                    )
+                else:
+                    params, opt, losses = train_segmented(
+                        params, opt, pos, neg_t, neg_h, consts.sample_w
+                    )
+                loss = losses.sum(axis=1) / jnp.maximum(
+                    consts.step_mask.sum(axis=1), 1
                 )
             if eaxis is not None:
                 params, opt = map_relation(
@@ -985,6 +1081,7 @@ class CycleEngine:
             "round", kind=kind, **attrs,
             client_steps=rounds * self.client_steps,
             client_steps_real=rounds * self.client_steps_real,
+            train_segments=self.train_segments if rounds else 0,
         )
 
     def train_cycle(self, state: FederationState):

@@ -19,12 +19,14 @@ import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.engine import make_client_mesh
 from repro.core.protocol import build_comm_views
-from repro.core.state import CycleEngine, SuperstepEngine
+from repro.core.state import CycleEngine, SuperstepEngine, step_segments
 from repro.core.sync import compress_schedule
 from repro.data import generate_kg, partition_by_relation
 from repro.federated.client import KGEClient
@@ -353,6 +355,95 @@ def test_flat_trainer_rejects_unequal_adam_steps():
     clients[0].train_local(1)  # client 0 advances its Adam step alone
     with pytest.raises(ValueError, match="lockstep"):
         engine.init_state(clients)
+
+
+# ------------------------------------------ segmented trainer == masked scan
+def test_step_segments_cover_every_real_step_once_in_order():
+    """The static schedule: each client's real scan indices appear exactly
+    once and in order, and the segments are non-empty over shrinking sets
+    of clients."""
+    segs = step_segments([105, 269, 109], 1)  # R3's batches at B=512
+    assert [(c.tolist(), i.shape[1]) for c, i in segs] == [
+        ([0, 1, 2], 105), ([1, 2], 4), ([1], 160)
+    ]
+    rng = np.random.default_rng(0)
+    for _ in range(200):
+        steps = rng.integers(1, 12, size=int(rng.integers(1, 6)))
+        epochs = int(rng.integers(1, 4))
+        mask = np.tile(np.arange(steps.max()), epochs)[None, :] < steps[:, None]
+        seen = [[] for _ in steps]
+        prev = None
+        for clients, idx in step_segments(steps, epochs):
+            assert len(clients) > 0 and idx.shape[1] > 0
+            assert idx.shape[0] == len(clients)
+            if prev is not None:
+                assert set(clients.tolist()) < set(prev.tolist())
+            prev = clients
+            for c, row in zip(clients, idx):
+                seen[c].extend(row.tolist())
+        for c, got in enumerate(seen):
+            assert got == np.flatnonzero(mask[c]).tolist(), (steps, epochs)
+
+
+@pytest.mark.parametrize("method,local_epochs,unequal_batches,seed", [
+    ("transe", 1, False, 0),
+    ("transe", 3, True, 1),
+    ("complex", 1, True, 2),
+    ("complex", 3, False, 3),
+])
+def test_segmented_trainer_matches_masked_scan(method, local_epochs,
+                                               unequal_batches, seed):
+    """On one device the trainer scans only the clients that still have
+    real steps; on a (one-device) client mesh it runs the masked scan over
+    every client.  Over randomized heterogeneous federations both give the
+    same trajectory: the same Adam step counts and download counts (the
+    ledger's), exactly, and params, moments, history and losses to 1e-6 of
+    each leaf's largest value.  Not bitwise: XLA:CPU fuses plain Adam's
+    arithmetic otherwise than Adam behind the masked scan's ``select``, and
+    the results differ in the last bit."""
+    rng = np.random.default_rng(seed)
+    num_clients = int(rng.integers(2, 5))
+    kg = generate_kg(num_entities=int(rng.integers(100, 180)),
+                     num_relations=3 * num_clients,
+                     num_triples=int(rng.integers(700, 1400)),
+                     seed=int(rng.integers(0, 1000)))
+    cd = partition_by_relation(kg, num_clients, seed=seed)
+    dim = int(rng.choice([8, 16]))
+    batch = [32] * num_clients
+    if unequal_batches:
+        batch[0] = 48
+
+    def mk():
+        return [KGEClient(d, method=method, dim=dim, batch_size=b,
+                          num_negatives=4, lr=5e-3, seed=seed)
+                for d, b in zip(cd, batch)]
+
+    views = build_comm_views([d.local_to_global for d in cd], kg.num_entities)
+    kw = dict(sparsity_p=0.5, local_epochs=local_epochs)
+    seg = CycleEngine(mk(), views, kg.num_entities, **kw)
+    masked = CycleEngine(mk(), views, kg.num_entities,
+                         mesh=make_client_mesh(1), **kw)
+    assert seg._segments is not None and len(seg._segments) > 1
+    assert masked._segments is None
+    assert seg._uniform_batches != unequal_batches
+    sa = seg.init_state(mk(), seed=seed)
+    sb = masked.init_state(mk(), seed=seed)
+
+    def close(a, b, what):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max(), what
+
+    for r, sync in enumerate((False, True, False)):
+        sa, da, la = seg.fused_cycle(sa, sync=sync)
+        sb, db, lb = masked.fused_cycle(sb, sync=sync)
+        np.testing.assert_array_equal(np.asarray(da), np.asarray(db))
+        close(la, lb, ("loss", r))
+    np.testing.assert_array_equal(np.asarray(sa.arrays.opt.step),
+                                  np.asarray(sb.arrays.opt.step))
+    for name in ("params", "opt", "hist"):
+        for i, (a, b) in enumerate(zip(jax.tree.leaves(getattr(sa.arrays, name)),
+                                       jax.tree.leaves(getattr(sb.arrays, name)))):
+            close(a, b, (name, i))
 
 
 # ----------------------------------------------------- eval filter-mask cache

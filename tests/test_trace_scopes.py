@@ -23,6 +23,7 @@ import pytest
 
 from repro.core import telemetry
 from repro.core.codecs import IdentityCodec
+from repro.core.engine import make_client_mesh
 from repro.core.evaluation import BatchedEvaluator
 from repro.core.protocol import build_comm_views
 from repro.core.state import CycleEngine
@@ -125,10 +126,17 @@ def test_scope_refuses_an_unknown_name():
 
 
 def test_counters_by_hand():
-    datas, clients, _, engine, _, evaluator = _federation(32)
+    datas, clients, views, engine, _, evaluator = _federation(32)
     steps = [c.loader.batches_per_epoch for c in clients]
-    assert engine.client_steps == len(datas) * max(steps)
-    assert engine.client_steps_real == sum(steps) < engine.client_steps
+    # one device: the segmented trainer computes the real client-steps only
+    assert engine.client_steps == engine.client_steps_real == sum(steps)
+    assert engine.train_segments == len(set(steps)) > 1
+    # under a client mesh the masked scan pads every client to the longest
+    meshed = CycleEngine(clients, views, engine.num_global, sparsity_p=0.5,
+                         local_epochs=1, mesh=make_client_mesh(1))
+    assert meshed.client_steps == len(datas) * max(steps)
+    assert meshed.client_steps_real == sum(steps) < meshed.client_steps
+    assert meshed.train_segments == 1
     bank = evaluator.banks["valid"]
     counts = [min(len(d.valid), 30) for d in datas]
     assert evaluator._eval_work["valid"] == {
@@ -181,6 +189,7 @@ def test_spans_reach_the_profiler_with_their_counters(tmp_path, monkeypatch):
     for a in rounds:
         assert a["client_steps"] == engine.client_steps
         assert a["client_steps_real"] == engine.client_steps_real
+        assert a["train_segments"] == engine.train_segments
     flushes = [a for n, a in events if n == "telemetry/ledger_flush"]
     assert flushes == [{"rounds": 2}]
     evals = [a for n, a in events if n == "telemetry/eval"]
