@@ -61,7 +61,7 @@ def altered_block(cfg, splits, seed, split, max_triples) -> np.ndarray:
     for c, s in enumerate(splits):
         p = reference.init_tables(seed, c, s.num_entities,
                                   cfg["dataset"]["num_relations"], cfg["dim"],
-                                  cfg["gamma"], jnp.float32)
+                                  cfg["gamma"], jnp.float32, cfg["method"])
         rows = getattr(s, split)[:max_triples]
         ranks = reference.filtered_ranks(
             p, rows, np.concatenate([s.train, s.valid, s.test]),

@@ -14,18 +14,26 @@ F32 = 4
 # floating-point operations of one score, per method, at width ``d``:
 # TransE gamma - ||h + r - t||: add, subtract, square, sum (4d) and a root;
 # ComplEx Re<h, r, conj t>: four triple products and three adds per
-# complex coordinate, then the sum over the d/2 coordinates (6d).
+# complex coordinate, then the sum over the d/2 coordinates (6d);
+# RotatE gamma - sum |h e^{i phase} - t|: per complex coordinate the
+# rotation (4 products, 2 adds), the subtraction of t (2), the squares and
+# their add (3), the root (1) and the sum (1), 13 over d/2 coordinates
+# (6.5d); the phase's sine and cosine are per relation, not per score.
 SCORE_FLOPS = {
     "transe": lambda d: 4 * d + 1,
     "complex": lambda d: 6 * d,
+    "rotate": lambda d: 6.5 * d,
 }
 
 # one candidate scored against a query row the eval folds beforehand:
 # distance family ||q - c|| (subtract, square, sum: 3d, and a root);
-# bilinear family q . c (2d).
+# bilinear family q . c (2d); RotatE sum |q - c| with q the rotated query,
+# per complex coordinate the subtraction (2), the squares and their add
+# (3), the root (1) and the sum (1), 7 over d/2 coordinates (3.5d).
 CAND_FLOPS = {
     "transe": lambda d: 3 * d + 1,
     "complex": lambda d: 2 * d,
+    "rotate": lambda d: 3.5 * d,
 }
 
 
@@ -59,6 +67,23 @@ def cand_score_cost(method: str, queries: int, cands: int, dim: int) -> tuple[fl
     rows; reads both, writes the ``(queries, cands)`` score block."""
     return (queries * cands * CAND_FLOPS[method](dim),
             F32 * (queries * dim + cands * dim + queries * cands))
+
+
+def cand_kernel_roofline(ctx: dict, op: str) -> float | None:
+    """An eval kernel's share of its roofline, in %, from a metric reader's
+    ``ctx``.  Per eval call and leg (tail, head), each client's real
+    queries are scored against its real entities: the queries and candidate
+    rows read once and the score block written once.  None where no op
+    named ``op`` ran."""
+    cfg, sh, tr = ctx["cfg"], ctx["shapes"], ctx["trace"]
+    if not tr.op_calls(op):
+        return None
+    flops = nbytes = 0.0
+    for q, e in zip(sh["queries"], sh["entities"]):
+        f, b = cand_score_cost(cfg["method"], q, e, cfg["dim"])
+        flops, nbytes = flops + 2 * f, nbytes + 2 * b
+    n = ctx["work"]["calls"]
+    return roofline_pct(n * flops, n * nbytes, tr.op_s(op), ctx["peak"])
 
 
 def peaks(device_kind: str, path: str | None = None) -> dict:

@@ -347,7 +347,8 @@ def reference_eval(cfg, splits, seed, traffic, split, dtype=jnp.float32):
     with jax.default_matmul_precision("highest"):
         params = [reference.init_tables(seed, c, s.num_entities,
                                         cfg["dataset"]["num_relations"],
-                                        cfg["dim"], cfg["gamma"], dtype)
+                                        cfg["dim"], cfg["gamma"], dtype,
+                                        cfg["method"])
                   for c, s in enumerate(splits)]
         return reference.evaluate(params, splits, split,
                                   traffic["max_eval_triples"], cfg["method"],

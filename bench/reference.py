@@ -11,6 +11,23 @@ per client in array operations, and the sync round as the full exchange
 FedE specifies (every shared row replaced by its mean over the clients
 that hold it); ranking scores every candidate directly.
 
+Methods (``METHODS``): TransE and ComplEx as this reproduction states them
+(both tables ``dim`` wide, uniform in +-(gamma + 2) / dim), and RotatE as
+its published code defines it (Sun et al., ICLR 2019): ``hidden = dim/2``
+complex coordinates, entities ``(E, dim)`` and relations ``(R, hidden)``
+both uniform in +-range, ``range = (gamma + 2) / hidden``, the relation
+read as the phase ``r / (range / pi)``.  Every method scores each step's
+corrupted tails and heads together, one softmax over both sides' negatives;
+the published RotatE alternates head-batch and tail-batch steps instead.
+
+Blocking: where one side's ``(B, N, D)`` float32 block of negative rows is
+over ``BUDGET``, a training step runs in blocks along the negative axis
+(every score first, holding no residuals, for the self-adversarial
+weights; then each block's weighted term differentiated through the table
+indexing and summed), and ranking scores ``min(32, BUDGET // (E*D*4))``
+rows at a time.  Loss and gradient are the same; only the order of the
+sums changes.  Under the budget the step and ranking run unblocked.
+
 ``dtype`` runs the whole reference in another float type: bfloat16 is the
 control, the precision a later change might be tempted to train in.
 ``half_batch`` plants a fault: each step's loss is the mean over the first
@@ -19,15 +36,19 @@ runs as one more sparse round.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 import time
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 B1, B2, EPS = 0.9, 0.999, 1e-8
-EPSILON = 2.0  # init range (gamma + EPSILON) / dim, as FedE and RotatE
+EPSILON = 2.0  # init range (gamma + EPSILON) / width, as FedE and RotatE
+BUDGET = 1 << 30  # bytes of float32 negative or candidate rows held at once
 
 
 def transe(h, r, t, gamma):
@@ -42,27 +63,133 @@ def complex_(h, r, t, gamma):
     return jnp.sum(hr * rr * tr + hi * rr * ti + hr * ri * ti - hi * ri * tr, axis=-1)
 
 
-SCORES = {"transe": (transe, True), "complex": (complex_, False)}
+def rotate(h, r, t, gamma):
+    """gamma - sum_j |h_j e^{i phase_j} - t_j| over the ``hidden`` complex
+    coordinates; ``r`` in embedding units, ``phase = r / (range / pi)``."""
+    d = r.shape[-1]
+    phase = r / ((gamma + EPSILON) / d / math.pi)
+    hr, hi = h[..., :d], h[..., d:]
+    tr, ti = t[..., :d], t[..., d:]
+    re = hr * jnp.cos(phase) - hi * jnp.sin(phase) - tr
+    im = hr * jnp.sin(phase) + hi * jnp.cos(phase) - ti
+    return gamma - jnp.sum(jnp.sqrt(re * re + im * im), axis=-1)
+
+
+@dataclasses.dataclass(frozen=True)
+class Method:
+    """A scoring method: its score ``(h, r, t, gamma)``, whether negatives
+    are weighted self-adversarially (else uniformly), the relation table's
+    width from ``dim``, and the init range from ``(gamma, dim)``: both
+    tables are drawn uniform in +-range."""
+
+    score: Callable
+    adversarial: bool
+    rel_width: Callable[[int], int]
+    init_range: Callable[[float, int], float]
+
+
+def _range_over_dim(gamma, dim):
+    return (gamma + EPSILON) / dim
+
+
+METHODS = {
+    "transe": Method(transe, True, lambda dim: dim, _range_over_dim),
+    "complex": Method(complex_, False, lambda dim: dim, _range_over_dim),
+    "rotate": Method(rotate, True, lambda dim: dim // 2,
+                     lambda gamma, dim: (gamma + EPSILON) / (dim // 2)),
+}
 
 
 def init_tables(seed: int, cid: int, num_entities: int, num_relations: int,
-                dim: int, gamma: float, dtype):
-    """A client's initial tables: uniform in +-(gamma + 2) / dim."""
+                dim: int, gamma: float, dtype, method: str):
+    """A client's initial tables, by the method's width and range rules."""
+    m = METHODS[method]
     k_e, k_r = jax.random.split(jax.random.PRNGKey(seed * 9973 + cid))
-    lim = (gamma + EPSILON) / dim
+    lim = m.init_range(gamma, dim)
     ent = jax.random.uniform(k_e, (num_entities, dim), minval=-lim, maxval=lim)
-    rel = jax.random.uniform(k_r, (num_relations, dim), minval=-lim, maxval=lim)
+    rel = jax.random.uniform(k_r, (num_relations, m.rel_width(dim)),
+                             minval=-lim, maxval=lim)
     return {"entity": ent.astype(dtype), "relation": rel.astype(dtype)}
 
 
+def neg_block(batch: int, negatives: int, dim: int, budget: int = BUDGET) -> int:
+    """Negatives a training step takes at once: all of them where one
+    side's ``(B, N, D)`` float32 block fits ``budget``; else the largest
+    divisor of N whose blocks of both sides fit it together (at least 1)."""
+    if batch * negatives * dim * 4 <= budget:
+        return negatives
+    fits = [n for n in range(1, negatives + 1)
+            if negatives % n == 0 and 2 * batch * n * dim * 4 <= budget]
+    return max(fits, default=1)
+
+
+def _blocked_value_and_grad(p, tri, nt, nh, *, score, adversarial, gamma,
+                            temp, rows, nb):
+    """Loss and gradient of one step, ``nb`` negatives a side at a time.
+
+    Pass 1 scores every negative, differentiating nothing, and fixes the
+    negative weights; pass 2 takes each block's weighted term's gradient
+    through the table indexing and adds it up.  Row ``i`` of the batch
+    counts ``1 / (2 rows)`` where ``i < rows``, as ``mean(per[:rows]) / 2``.
+    """
+    b, n = nt.shape
+    blocks = n // nb
+    row_w = jnp.where(jnp.arange(b) < rows, 0.5 / rows, 0.0)
+    nt_b = nt.reshape(b, blocks, nb).transpose(1, 0, 2)
+    nh_b = nh.reshape(b, blocks, nb).transpose(1, 0, 2)
+
+    def rows_of(q):
+        ent, rel = q["entity"], q["relation"]
+        return ent, ent[tri[:, 0]], rel[tri[:, 1]], ent[tri[:, 2]]
+
+    def sides(q, nt_j, nh_j):
+        ent, h, r, t = rows_of(q)
+        return (score(h[:, None], r[:, None], ent[nt_j], gamma),
+                score(ent[nh_j], r[:, None], t[:, None], gamma))
+
+    _, h, r, t = rows_of(p)
+    pos_s = score(h, r, t, gamma)
+    st, sh = jax.lax.map(lambda x: sides(p, *x), (nt_b, nh_b))
+    neg_s = jnp.concatenate([st.transpose(1, 0, 2).reshape(b, n),
+                             sh.transpose(1, 0, 2).reshape(b, n)], axis=-1)
+    if adversarial:
+        w = jax.nn.softmax(neg_s * temp, axis=-1)
+    else:
+        w = jnp.full_like(neg_s, 1.0 / neg_s.shape[-1])
+    per = -jax.nn.log_sigmoid(pos_s) - jnp.sum(
+        w * jax.nn.log_sigmoid(-neg_s), axis=-1)
+    loss = jnp.sum(per[:rows]) / rows / 2.0
+    w_t = w[:, :n].reshape(b, blocks, nb).transpose(1, 0, 2)
+    w_h = w[:, n:].reshape(b, blocks, nb).transpose(1, 0, 2)
+
+    def pos_term(q):
+        _, h, r, t = rows_of(q)
+        return jnp.sum(row_w * -jax.nn.log_sigmoid(score(h, r, t, gamma)))
+
+    def block_term(q, nt_j, nh_j, wt_j, wh_j):
+        a, c = sides(q, nt_j, nh_j)
+        return -jnp.sum(row_w[:, None] * (wt_j * jax.nn.log_sigmoid(-a)
+                                         + wh_j * jax.nn.log_sigmoid(-c)))
+
+    def add_block(g, x):
+        gj = jax.grad(block_term)(p, *x)
+        return jax.tree.map(jnp.add, g, gj), None
+
+    g, _ = jax.lax.scan(add_block, jax.grad(pos_term)(p),
+                        (nt_b, nh_b, w_t, w_h))
+    return loss, g
+
+
 @functools.partial(
-    jax.jit, static_argnames=("method", "gamma", "lr", "temp", "half_batch")
+    jax.jit,
+    static_argnames=("method", "gamma", "lr", "temp", "half_batch", "budget"),
 )
 def train_client(params, mu, nu, step, pos, neg_t, neg_h, *, method, gamma,
-                 lr, temp, half_batch):
+                 lr, temp, half_batch, budget=BUDGET):
     """Adam steps over ``pos`` ``(S, B, 3)``; returns the new state and the
     mean loss of the steps."""
-    score, adversarial = SCORES[method]
+    spec = METHODS[method]
+    score, adversarial = spec.score, spec.adversarial
 
     def loss_fn(p, tri, nt, nh):
         ent, rel = p["entity"], p["relation"]
@@ -83,9 +210,18 @@ def train_client(params, mu, nu, step, pos, neg_t, neg_h, *, method, gamma,
             per = per[: per.shape[0] // 2]
         return jnp.mean(per) / 2.0
 
+    b, n = neg_t.shape[1:]
+    nb = neg_block(b, n, params["entity"].shape[1], budget)
+    if nb == n:
+        value_and_grad = jax.value_and_grad(loss_fn)
+    else:
+        value_and_grad = functools.partial(
+            _blocked_value_and_grad, score=score, adversarial=adversarial,
+            gamma=gamma, temp=temp, rows=b // 2 if half_batch else b, nb=nb)
+
     def step_fn(carry, x):
         p, m, v, k = carry
-        loss, g = jax.value_and_grad(loss_fn)(p, *x)
+        loss, g = value_and_grad(p, *x)
         k = k + 1
         m = jax.tree.map(lambda a, b: B1 * a + (1 - B1) * b, m, g)
         v = jax.tree.map(lambda a, b: B2 * a + (1 - B2) * b * b, v, g)
@@ -159,7 +295,8 @@ class Federation:
         self.clients = clients
         d, g = cfg["dim"], cfg["gamma"]
         r_n = cfg["num_relations"]
-        self.params = [init_tables(seed, c, s.num_entities, r_n, d, g, dtype)
+        self.params = [init_tables(seed, c, s.num_entities, r_n, d, g, dtype,
+                                   cfg["method"])
                        for c, s in enumerate(clients)]
         self.params0 = [dict(p) for p in self.params]
         zeros = lambda p: {k: jnp.zeros_like(v) for k, v in p.items()}  # noqa: E731
@@ -280,7 +417,7 @@ class Federation:
 def _beats(ent, rel, tri, *, method, gamma):
     """Per row of ``tri`` ``(blocks, Bk, 3)``: the gold score and how many
     candidates other than the gold one outscore it, tail and head legs."""
-    score = SCORES[method][0]
+    score = METHODS[method].score
     cand = jnp.arange(ent.shape[0])
 
     def block(x):
@@ -297,11 +434,17 @@ def _beats(ent, rel, tri, *, method, gamma):
 
 @functools.partial(jax.jit, static_argnames=("method", "gamma"))
 def _pair_scores(ent, rel, h, r, t, *, method, gamma):
-    return SCORES[method][0](ent[h], rel[r], ent[t], gamma)
+    return METHODS[method].score(ent[h], rel[r], ent[t], gamma)
+
+
+def rank_block(num_entities: int, dim: int, budget: int = BUDGET) -> int:
+    """Rows ranked at once: 32, or fewer where their ``(rows, E, D)``
+    float32 candidate block would be over ``budget`` (at least 1)."""
+    return max(1, min(32, budget // (num_entities * dim * 4)))
 
 
 def filtered_ranks(params: dict, split: np.ndarray, known: np.ndarray,
-                   method: str, gamma: float, block: int = 32):
+                   method: str, gamma: float, budget: int = BUDGET):
     """Filtered ranks ``(n, 2)`` (tail leg, head leg) of ``split``'s triples
     against every entity of the client, ``known`` being all its triples.
 
@@ -311,6 +454,7 @@ def filtered_ranks(params: dict, split: np.ndarray, known: np.ndarray,
     """
     n = split.shape[0]
     ent, rel = params["entity"], params["relation"]
+    block = rank_block(*ent.shape, budget)
     pad = (-n) % block
     tri = np.concatenate([split, np.repeat(split[:1], pad, 0)]).astype(np.int32)
     gold, bt, bh = (np.asarray(x).reshape(-1)[:n] for x in _beats(

@@ -23,6 +23,7 @@ from repro.kge import scoring
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TRAIN, EVAL = "r3-transe.train", "r3-transe.eval"
+BILINEAR_EVAL = "r5-complex.eval"
 
 
 def setting(cell):
@@ -81,7 +82,7 @@ def test_sync_round_run_as_a_sparse_round(monkeypatch):
     assert not run(TRAIN)["correct"]
 
 
-@pytest.mark.parametrize("cell", [TRAIN, EVAL])
+@pytest.mark.parametrize("cell", [TRAIN, EVAL, BILINEAR_EVAL])
 def test_answer_altered_where_produced(monkeypatch, cell):
     evaluate = BatchedEvaluator.evaluate
 
@@ -101,6 +102,20 @@ def test_control_and_faults_fail_the_limits():
     for name, numbers in control.train_readings(cfg, traffic, splits, 7).items():
         assert not check.judge(numbers, limits)[0], name
     cfg, traffic, limits, _ = setting(EVAL)
+    for name, numbers in control.eval_readings(cfg, traffic, splits, 7).items():
+        assert not check.judge(numbers, limits)[0], name
+
+
+def test_sound_bilinear_eval_run_is_correct(monkeypatch):
+    # the Pallas kernels in interpret mode, so that the run ranks through
+    # bilinear_cand_score_pallas as the cell does on the chip
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert run(BILINEAR_EVAL)["correct"]
+
+
+def test_bilinear_eval_control_and_fault_fail_the_limits():
+    cfg, traffic, limits, _ = setting(BILINEAR_EVAL)
+    splits = kgdata.federation(cfg["dataset"], cfg["num_clients"])
     for name, numbers in control.eval_readings(cfg, traffic, splits, 7).items():
         assert not check.judge(numbers, limits)[0], name
 
