@@ -24,9 +24,11 @@ Blocking: where one side's ``(B, N, D)`` float32 block of negative rows is
 over ``BUDGET``, a training step runs in blocks along the negative axis
 (every score first, holding no residuals, for the self-adversarial
 weights; then each block's weighted term differentiated through the table
-indexing and summed), and ranking scores ``min(32, BUDGET // (E*D*4))``
-rows at a time.  Loss and gradient are the same; only the order of the
-sums changes.  Under the budget the step and ranking run unblocked.
+indexing and summed), ranking scores ``min(32, BUDGET // (E*D*4))`` rows at
+a time, and where one side's rows of a leg's filtered pairs are over
+``BUDGET``, they are scored ``BUDGET // (2*D*4)`` pairs at a time.  Loss,
+gradient and ranks are the same; only the order of the sums changes.
+Under the budget the step, ranking and filter run unblocked.
 
 ``dtype`` runs the whole reference in another float type: bfloat16 is the
 control, the precision a later change might be tempted to train in.
@@ -437,6 +439,15 @@ def _pair_scores(ent, rel, h, r, t, *, method, gamma):
     return METHODS[method].score(ent[h], rel[r], ent[t], gamma)
 
 
+def pair_block(pairs: int, dim: int, budget: int = BUDGET) -> int:
+    """Filtered pairs scored at once: all of them where one side's
+    ``(pairs, D)`` float32 rows fit ``budget``; else as many as both sides'
+    rows fit it together (at least 1)."""
+    if pairs * dim * 4 <= budget:
+        return max(1, pairs)
+    return max(1, budget // (2 * dim * 4))
+
+
 def rank_block(num_entities: int, dim: int, budget: int = BUDGET) -> int:
     """Rows ranked at once: 32, or fewer where their ``(rows, E, D)``
     float32 candidate block would be over ``budget`` (at least 1)."""
@@ -479,9 +490,14 @@ def filtered_ranks(params: dict, split: np.ndarray, known: np.ndarray,
             continue
         h = split[rows, 0] if leg == 0 else cand
         t = cand if leg == 0 else split[rows, 2]
-        s = np.asarray(_pair_scores(ent, rel, jnp.asarray(h),
-                                    jnp.asarray(split[rows, 1]),
-                                    jnp.asarray(t), method=method, gamma=gamma))
+        r = split[rows, 1]
+        step = pair_block(rows.size, ent.shape[1], budget)
+        s = np.concatenate([
+            np.asarray(_pair_scores(ent, rel, jnp.asarray(h[i:i + step]),
+                                    jnp.asarray(r[i:i + step]),
+                                    jnp.asarray(t[i:i + step]), method=method,
+                                    gamma=gamma))
+            for i in range(0, rows.size, step)])
         np.subtract.at(out, rows, (s > gold[rows]).astype(np.int64))
     return np.stack([bt + 1, bh + 1], axis=1)
 

@@ -19,7 +19,6 @@ import kgdata
 from repro.core import state as core_state
 from repro.core.evaluation import BatchedEvaluator
 from repro.core.state import CycleEngine, FederationState
-from repro.kge import scoring
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TRAIN, EVAL = "r3-transe.train", "r3-transe.eval"
@@ -61,7 +60,7 @@ def test_state_left_unchanged(monkeypatch):
 
 
 def test_half_of_the_batch_left_out(monkeypatch):
-    whole = scoring.loss_from_scores
+    whole = core_state.loss_from_scores
 
     def half(pos, neg, method, temp=1.0, sample_weight=None):
         b = pos.shape[0] // 2

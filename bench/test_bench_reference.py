@@ -1,5 +1,7 @@
-"""The plain reference's RotatE, its blocking rule and the RotatE costs; the
-bilinear eval kernel's roofline reader on a trace built by hand."""
+"""The plain reference's RotatE, its blocking rules and the RotatE costs; the
+bilinear eval kernel's roofline reader on a trace built by hand.  Nothing
+here imports the program: how the program parametrises a method is its own
+tests' business."""
 import importlib.util
 import math
 import os
@@ -12,7 +14,6 @@ import pytest
 import costs
 import reference
 import tracereduce as tr
-from repro.kge import scoring
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 GAMMA = 9.0  # the published FB15k-237 RotatE setting
@@ -23,25 +24,32 @@ def tables(method, dim, entities=40, relations=6, seed=2**31 + 5, cid=1):
                                  jnp.float32, method)
 
 
+def complex_rows(x):
+    """``(n, D)`` real rows as ``(n, D/2)`` complex ones: real half first."""
+    x = np.asarray(x, np.float64)
+    d = x.shape[-1] // 2
+    return x[..., :d] + 1j * x[..., d:]
+
+
 def test_rotate_score_is_the_programs_on_the_phase():
+    """The reference's RotatE is the published formula, written out here in
+    complex arithmetic: gamma - sum_j |h_j exp(i phase_j) - t_j|, with the
+    relation read as the phase r / (range / pi)."""
     dim = 64
     p = tables("rotate", dim)
     rng = (GAMMA + 2) / (dim // 2)
     ent, rel = p["entity"], p["relation"]
     h, t = ent[:24], ent[16:40]
     r = jnp.tile(rel, (4, 1))
-    got = reference.rotate(h, r, t, GAMMA)
-    # phase = r * pi / range, rounded as the reference rounds it; the
-    # program adds 1e-12 under each root and squares by integer power:
+    rot = np.exp(1j * np.asarray(r, np.float64) / (rng / math.pi))
+    want = GAMMA - np.sum(np.abs(complex_rows(h) * rot - complex_rows(t)), axis=-1)
     # float32 rounding of scores of order gamma
-    want = scoring.rotate_score(h, r / (rng / math.pi), t, GAMMA)
-    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    np.testing.assert_allclose(reference.rotate(h, r, t, GAMMA), want, rtol=0,
+                               atol=1e-5)
     # and it is a distance: a tail equal to the rotated head scores gamma
-    phase = r * math.pi / rng
-    c, s = jnp.cos(phase), jnp.sin(phase)
-    hr, hi = h[:, :dim // 2], h[:, dim // 2:]
-    rot = jnp.concatenate([hr * c - hi * s, hr * s + hi * c], axis=-1)
-    np.testing.assert_allclose(reference.rotate(h, r, rot, GAMMA), GAMMA,
+    hr = complex_rows(h) * rot
+    tail = jnp.asarray(np.concatenate([hr.real, hr.imag], axis=-1), jnp.float32)
+    np.testing.assert_allclose(reference.rotate(h, r, tail, GAMMA), GAMMA,
                                rtol=0, atol=1e-5)
 
 
@@ -55,17 +63,10 @@ def test_rotate_tables_at_the_published_widths():
     for leaf in p.values():
         top = float(jnp.max(jnp.abs(leaf)))
         assert 0.99 * rng < top <= rng
-    model = scoring.KGEModel(method="rotate", num_entities=300, num_relations=20,
-                             dim=dim, gamma=GAMMA)
-    prog = scoring.init_kge_params(jax.random.PRNGKey(seed * 9973 + cid), model)
-    # the same key draws the relation as a phase in +-pi: the reference's
-    # draw, read as a phase, is that phase (float32 rounding of values up
-    # to pi)
-    np.testing.assert_allclose(p["relation"] * math.pi / rng, prog["relation"],
-                               rtol=0, atol=1e-6)
-    # the program's entity range is (gamma + 2) / dim, half the published
-    # one: the same draw, half as wide (float32 rounding)
-    np.testing.assert_allclose(p["entity"], 2 * prog["entity"], rtol=0, atol=1e-6)
+    # read as a phase, the relation table covers the whole circle, +-pi
+    # (float64 rounding of r * pi / range at r = range)
+    phase = np.abs(np.asarray(p["relation"], np.float64)) * math.pi / rng
+    assert 0.99 * math.pi < phase.max() <= math.pi * (1 + 1e-12)
 
 
 def test_block_rules_at_the_cells_sizes():
@@ -78,6 +79,13 @@ def test_block_rules_at_the_cells_sizes():
     assert reference.rank_block(14537, 2000) == 9
     assert reference.neg_block(4, 7, 8, budget=1) == 1
     assert reference.rank_block(10**9, 2000) == 1
+    # the filter's pairs: the largest leg of today's cells, R3's second
+    # client's whole test split (632,235 pairs, 647 MB a side at D=256), is
+    # one call; at D=2000 it is 5.1 GB a side, blocks of 67,108 pairs
+    assert reference.pair_block(632235, 256) == 632235
+    assert reference.pair_block(632235, 2000) == 67108
+    assert reference.pair_block(0, 2000) == 1
+    assert reference.pair_block(10, 2000, budget=1) == 1
 
 
 def _steps(method, budget, half_batch, steps=3, b=8, n=6, dim=16):
@@ -128,6 +136,36 @@ def test_blocked_ranking_gives_the_same_ranks(method):
     # at once, and 37 rows pad to 13 blocks
     assert reference.rank_block(40, 16, 7680) == 3
     blocked = reference.filtered_ranks(p, split, known, method, GAMMA, budget=7680)
+    np.testing.assert_array_equal(blocked, whole)
+    assert whole.min() >= 1 and whole.max() > 1
+
+
+@pytest.mark.parametrize("method", ["transe", "complex", "rotate"])
+def test_blocked_filter_gives_the_same_ranks(method, monkeypatch):
+    p = tables(method, 16)
+    rng = np.random.default_rng(4)
+    known = np.stack([rng.integers(0, 40, 300), rng.integers(0, 6, 300),
+                      rng.integers(0, 40, 300)], axis=1)
+    split = known[:37]
+    calls = []
+    pair_scores = reference._pair_scores
+
+    def counted(ent, rel, h, r, t, **kw):
+        calls.append(h.shape[0])
+        return pair_scores(ent, rel, h, r, t, **kw)
+
+    monkeypatch.setattr(reference, "_pair_scores", counted)
+    whole = reference.filtered_ranks(p, split, known, method, GAMMA)
+    legs = list(calls)
+    # 16 float32 coordinates are 64 bytes a side: a budget of 448 holds one
+    # side of 7 pairs, so a leg of more is scored 3 pairs (384 bytes) at a
+    # time
+    assert reference.pair_block(8, 16, 448) == 3
+    calls.clear()
+    blocked = reference.filtered_ranks(p, split, known, method, GAMMA, budget=448)
+    assert len(legs) == 2 and min(legs) > 7
+    assert max(calls) == 3 and sum(calls) == sum(legs)
+    assert len(calls) == sum(-(-n // 3) for n in legs)
     np.testing.assert_array_equal(blocked, whole)
     assert whole.min() >= 1 and whole.max() > 1
 
