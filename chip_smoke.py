@@ -64,6 +64,7 @@ FB15K237 = dict(num_entities=14541, num_relations=237, num_triples=310116)
 KERNELS = {"_change_score_kernel": "change_score",
            "_sparse_apply_kernel": "sparse_apply",
            "_dist_cand_kernel": "dist_cand_score",
+           "_transe_mxu_kernel": "dist_cand_score",
            "_bilinear_kernel": "bilinear_cand_score"}
 IR_DIR = os.path.join(CHECKOUT, ".jax_ir")
 

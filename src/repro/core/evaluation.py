@@ -270,16 +270,20 @@ class BatchedEvaluator:
         }
         # work counters of the ``eval`` telemetry span, per split: candidate
         # scores an evaluate call computes (both legs, rows and candidates
-        # padded as the program runs them) and those of real triples
-        # against their client's real entities
+        # padded as the program runs them), those of them the kernels
+        # reduce on the MXU, and those of real triples against their
+        # client's real entities
         if self._eaxis is None:
             scan_w = self.e_pad
         else:  # each shard scans its own chunk-padded block
             scan_w = n_e * (-(-(self.e_pad // n_e) // self.chunk) * self.chunk)
         n_ent = [int(d.num_entities) for d in datas]
+        mxu_legs = kernel_ops.cand_legs_on_mxu(method)
         self._eval_work = {
             s: {
                 "scores": len(datas) * b.triples.shape[1] * 2 * scan_w,
+                "scores_mxu": len(datas) * b.triples.shape[1] * mxu_legs
+                * scan_w,
                 "scores_real": sum(
                     2 * int(n) * e for n, e in zip(np.asarray(b.count), n_ent)
                 ),

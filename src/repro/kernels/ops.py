@@ -18,6 +18,7 @@ from repro.kernels import ref
 from repro.kernels.bilinear_score import bilinear_cand_score_pallas
 from repro.kernels.change_score import change_score_pallas
 from repro.kernels.kge_score import (
+    MXU_MODES,
     dist_cand_score_pallas,
     rotate_neg_score_pallas,
     transe_neg_score_pallas,
@@ -122,6 +123,18 @@ def kge_cand_scores(h, r, t, cand, method: str, gamma: float):
     for _ in range(h.ndim - 2):  # leading client axes
         fn = jax.vmap(fn)
     return fn(q_t, cand), (hs if hs is not None else fn(q_h, cand))
+
+
+def cand_legs_on_mxu(method: str) -> int:
+    """How many of the two legs :func:`kge_cand_scores` reduces on the MXU
+    under this process's dispatch: both for TransE and the bilinear family
+    (the tail leg alone where the head leg does not fold), none for the
+    other distance methods or on the ref path."""
+    spec = scoring.get_scoring(method)
+    if _mode() == "ref" or (spec.family == "distance"
+                            and spec.kernel_mode not in MXU_MODES):
+        return 0
+    return 2 if spec.fold_head else 1
 
 
 def sparse_apply(emb, agg, priority, sign) -> jnp.ndarray:

@@ -86,7 +86,7 @@ class ScoringSpec:
     # and kge_cand_scores evaluates that leg by broadcasting ``score``
     # exactly on every path; the tail leg still rides the eval kernel.
     fold_head: bool = True
-    # distance family only: which distance _dist_cand_kernel computes.
+    # distance family only: which distance dist_cand_score_pallas computes.
     kernel_mode: str | None = None
     # extra static kwargs for the distance kernel, from (gamma, true dim).
     kernel_statics: Callable[[float, int], dict] = _no_kernel_statics

@@ -96,6 +96,43 @@ def test_dist_cand_score_kernel(b, n, d, method):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("b,n,d", [(300, 700, 256), (17, 513, 100)])
+def test_dist_cand_score_transe_default_tiles(b, n, d):
+    """TransE's MXU form at the tiles the wrapper picks, with B and N that
+    are no tile multiple, against a float64 distance."""
+    from repro.kernels.kge_score import dist_cand_score_pallas
+
+    rng = np.random.default_rng(b * n + d)
+    q = rng.normal(size=(b, d))
+    cand = rng.normal(size=(n, d))
+    want = 8.0 - np.linalg.norm(q[:, None, :] - cand[None, :, :], axis=-1)
+    got = dist_cand_score_pallas(jnp.asarray(q, jnp.float32),
+                                 jnp.asarray(cand, jnp.float32), 8.0,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("spread", [1e-2, 0.8])
+def test_dist_cand_score_transe_near_pairs_at_large_norms(spread):
+    """Query rows of norm 30 and candidates drawn around them: the MXU
+    form's ``||q||^2 + ||c||^2 - 2 q.c`` cancels about 1800 down to the
+    squared distance.  Spread 1e-2 per coordinate (distances about 0.16)
+    is the sub-blocks scored again from the difference; 0.8 (distances
+    about 13) stays on the MXU form.  Bound: 1e-4, against float64."""
+    from repro.kernels.kge_score import dist_cand_score_pallas
+
+    rng = np.random.default_rng(5)
+    b, n, d = 64, 512, 256
+    q = rng.normal(size=(b, d))
+    q *= 30.0 / np.linalg.norm(q, axis=1, keepdims=True)
+    cand = q[rng.integers(0, b, n)] + spread * rng.normal(size=(n, d))
+    want = 8.0 - np.linalg.norm(q[:, None, :] - cand[None, :, :], axis=-1)
+    got = dist_cand_score_pallas(jnp.asarray(q, jnp.float32),
+                                 jnp.asarray(cand, jnp.float32), 8.0,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+
+
 @pytest.mark.parametrize("b,n,d", [(4, 8, 32), (7, 150, 64), (16, 257, 100)])
 @pytest.mark.parametrize("method", ["distmult", "complex"])
 def test_bilinear_cand_score_kernel(b, n, d, method):

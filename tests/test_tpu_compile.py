@@ -4,7 +4,8 @@ Each kernel that ``kernels/ops.py`` dispatches on the main path is lowered
 and compiled by the TPU compiler for a described ``v5e:2x2`` topology at
 D=256: the round-table kernels at a flattened ``(C * Ns_max, D)`` shape,
 the eval kernels at the evaluator's ``(B, D) x (chunk, D)`` tiles (and
-under its client ``vmap``), and each round kernel once more inside a
+under its client ``vmap``, TransE's MXU tiles also at a whole R3 test
+split and at D=1024, which checks their VMEM rule), and each round kernel once more inside a
 ``shard_map`` over the four chips, as the pod engines call it.  A passing
 compile is not a chip run: it checks only that Mosaic accepts the kernel
 and that the compiled program calls it (``tpu_custom_call``).
@@ -29,6 +30,7 @@ from repro.kernels.sparse_apply import sparse_apply_pallas
 D = 256
 N_ROUND = 15104  # a flattened (C * Ns_max, D) round table
 B, CHUNK, C = 512, 512, 3  # eval query block, candidate chunk, clients
+B_EVAL = 17256  # the largest client's test split, R3 (the evaluator's B_max)
 
 
 @pytest.fixture(scope="module")
@@ -94,6 +96,14 @@ SINGLE = {
         jax.vmap(bilinear_cand_score_pallas),
         [((C, B, D), jnp.float32), ((C, CHUNK, D), jnp.float32)],
     ),
+    # TransE's MXU tiles at the R3 evaluator's whole test split, and wide
+    **{
+        f"dist_cand_score_transe_vmap_eval_d{d}": (
+            jax.vmap(_dist("transe")),
+            [((C, B_EVAL, d), jnp.float32), ((C, CHUNK, d), jnp.float32)],
+        )
+        for d in (D, 1024)
+    },
 }
 
 # kernels as the pod engines call them: per-shard, inside a shard_map

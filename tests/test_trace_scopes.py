@@ -141,10 +141,32 @@ def test_counters_by_hand():
     counts = [min(len(d.valid), 30) for d in datas]
     assert evaluator._eval_work["valid"] == {
         "scores": len(datas) * max(counts) * 2 * evaluator.e_pad,
+        # the ref dispatch reduces no candidate score on the MXU
+        "scores_mxu": 0,
         "scores_real": sum(2 * n * d.num_entities
                            for n, d in zip(counts, datas)),
     }
     assert bank.triples.shape[1] == max(counts)
+
+
+# legs the kernels reduce on the MXU: TransE's and the bilinear family's
+# (ProjE's head leg does not fold into a query row), none for RotatE
+@pytest.mark.parametrize("method,legs", [("transe", 2), ("rotate", 0),
+                                         ("complex", 2), ("proje", 1)])
+def test_mxu_counter_under_the_kernels(monkeypatch, method, legs):
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    kg = generate_kg(num_entities=120, num_relations=8, num_triples=800,
+                     seed=1)
+    datas = partition_by_relation(kg, 2, seed=0)
+    evaluator = BatchedEvaluator(
+        datas, method=method, gamma=8.0,
+        e_max=max(d.num_entities for d in datas), max_triples=30,
+        splits=("valid",),
+    )
+    work = evaluator._eval_work["valid"]
+    assert work["scores_mxu"] == work["scores"] * legs // 2
+    assert work["scores"] == (len(datas) * evaluator.banks["valid"]
+                              .triples.shape[1] * 2 * evaluator.e_pad)
 
 
 def _host_events(trace_dir):
